@@ -16,6 +16,9 @@ def main() -> None:
     ap.add_argument("--accuracy-steps", type=int, default=300)
     args = ap.parse_args()
 
+    from repro.launch.runtime import configure_jax
+    configure_jax()
+
     from benchmarks import (bench_accuracy, bench_codec_latency, bench_comm,
                             bench_roofline, bench_serving, bench_table1,
                             bench_table2)
@@ -36,12 +39,6 @@ def main() -> None:
         t0 = time.time()
         fn()
         print(f"# section {name}: {time.time()-t0:.1f}s", flush=True)
-
-    print("\n==== roofline (from dry-run artifacts, if present) ====", flush=True)
-    try:
-        bench_roofline.aggregate()
-    except Exception as e:  # dry-run artifacts may not exist yet
-        print(f"# roofline aggregation skipped: {e}")
 
     if not args.fast:
         print("\n==== table1_accuracy_trend (laptop-scale) ====", flush=True)

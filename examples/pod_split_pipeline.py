@@ -66,7 +66,7 @@ def main():
 
     data = SyntheticTokenDataset(cfg.vocab_size, S, seed=0)
     losses = []
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(STEPS):
             b = data.batch(B, i)
             params, opt_state, loss = step(

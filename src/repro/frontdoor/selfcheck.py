@@ -40,6 +40,7 @@ from repro.faults import FaultPlan
 from repro.frontdoor.admission import AdmissionController, TenantPolicy
 from repro.frontdoor.client import FrontDoorClient
 from repro.frontdoor.server import FrontDoorServer
+from repro.launch.runtime import configure_jax
 from repro.models import lm as lm_lib
 from repro.serving.engine import BatchedEngine
 
@@ -323,6 +324,7 @@ def main():
                          "event-loop stall detection); any invariant trip "
                          "exits nonzero")
     args = ap.parse_args()
+    configure_jax()
     if args.chaos:
         asyncio.run(amain_chaos(args.requests, sanitize=args.sanitize))
     elif args.spec_decode:

@@ -1,21 +1,23 @@
 """Pallas TPU kernels for C3-SL's HRR codec (bind+superpose / unbind).
 
-TPU adaptation (see DESIGN.md): instead of the GPU-friendly FFT route, the
-circular convolution is computed as a tiled Toeplitz-block contraction that
-runs on the MXU.  For an output tile d in [d0, d0+T) and an input tile
-j in [j0, j0+T), the key slice K[(d - j) mod D] is a T x T Toeplitz block
-built in-VMEM from a (2T-1)-window of the doubled key Kext = [K || K]:
+TPU adaptation: instead of the FFT route, the circular convolution is a
+tiled Toeplitz-block contraction on the MXU.  The entry for input position
+j and output position d is K[(d - j) mod D], so for output tile dt and
+input tile jt the T x T block depends only on (dt - jt) mod (D/T): each
+key has D/T distinct blocks, precomputed by :func:`toeplitz_blocks` and
+selected by the key operand's block index map.
 
     bind:    S[g, d]      = sum_i sum_j Z[g, i, j] * K_i[(d - j) mod D]
     unbind:  Zhat[g, i, d] = sum_j S[g, j] * K_i[(j - d) mod D]
 
+Unbind reads block (jt - dt) mod (D/T) and contracts with its transpose.
 Grid: (G/GT, D/T, D/T) with accumulation over the last (j-tile) grid axis.
-Each j-step does R small (GT x T) @ (T x T) MXU contractions.  FLOPs match
-the paper's Table 2 accounting (D^2 MACs per bound vector).
+Each j-step does R (GT x T) @ (T x T) MXU contractions.  FLOPs match the
+paper's Table 2 accounting (D^2 MACs per bound vector).
 
-VMEM budget per step (T=128, R=16, D=4096, GT=8, f32):
-    Z tile 8*16*128*4 = 64 KiB, Kext 16*8192*4 = 512 KiB,
-    Toeplitz scratch 128*128*4 = 64 KiB, out 8*128*4 = 4 KiB  -> ~0.7 MiB.
+VMEM per step (T=128, R=4, GT=8, f32, double-buffered inputs):
+    Z tile 2*8*8*128*4 = 64 KiB (R=4 pads to 8 sublanes), key blocks
+    2*4*128*128*4 = 512 KiB, out 8*128*4 = 4 KiB  -> ~0.6 MiB.
 """
 from __future__ import annotations
 
@@ -24,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _pick_tile(D: int, target: int = 128) -> int:
@@ -59,57 +62,49 @@ def _check_tile(D: int, T: int):
             f"a multiple of {MIN_TILE * MIN_TILE}.")
 
 
-def _window_indices(T: int):
-    ia = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)  # tile-local j (rows)
-    ib = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)  # tile-local d (cols)
-    return ia, ib
+def toeplitz_blocks(K: jax.Array, T: int) -> jax.Array:
+    """K (R, D) -> the (R, D/T, T, T) distinct Toeplitz blocks of the keys.
+
+    ``blocks[i, m, a, b] = K_i[(m*T + b - a) mod D]``: the bind matrix
+    entry for input row j and output column d depends only on
+    ``(d - j) mod D``, so tile pair (d-tile dt, j-tile jt) reads block
+    ``m = (dt - jt) mod (D/T)``, and unbind reads the transpose of block
+    ``(jt - dt) mod (D/T)``.  R*D*T values: 8 MiB at D=4096, R=4, T=128."""
+    R, D = K.shape
+    L = 2 * D
+    # circulant rows without a gather: T copies of [K || K] laid end to
+    # end and re-cut at row length L - 1 shift by one per row, so
+    # rows[i, a, x] = K_i[(x - a) mod D]; x = m*T + b splits the columns.
+    tiled = jnp.tile(jnp.concatenate([K, K], axis=-1), (1, T))
+    rows = tiled[:, :T * (L - 1)].reshape(R, T, L - 1)[:, :, :D]
+    return rows.reshape(R, T, D // T, T).transpose(0, 2, 1, 3)
 
 
-def _bind_kernel(z_ref, kext_ref, out_ref, *, T: int, R: int, D: int):
-    dt = pl.program_id(1)
-    jt = pl.program_id(2)
-    d0 = dt * T
-    j0 = jt * T
-
-    @pl.when(jt == 0)
+def _bind_kernel(z_ref, toep_ref, out_ref, *, R: int):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    z = z_ref[...].astype(jnp.float32)           # (GT, R, T)
-    ia, ib = _window_indices(T)
-    widx = ib - ia + (T - 1)                      # toep[a, b] <- win[b - a + T - 1]
-    # window start so that Kext[w0 + (b - a + T-1)] == K[(d0+b - j0-a) mod D]
-    w0 = d0 - j0 + D - (T - 1)
-    acc = jnp.zeros(out_ref.shape, jnp.float32)   # (GT, T)
+    acc = jnp.zeros(out_ref.shape, jnp.float32)   # (GT, T_d)
     for i in range(R):
-        win = jax.lax.dynamic_slice(kext_ref[i], (w0,), (2 * T - 1,))
-        toep = jnp.take(win, widx, axis=0)        # (T_j, T_d)
-        acc += jnp.dot(z[:, i, :], toep, preferred_element_type=jnp.float32)
+        z_i = z_ref[:, i, :].astype(jnp.float32)  # (GT, T_j)
+        acc += jnp.dot(z_i, toep_ref[i, 0].astype(jnp.float32),
+                       preferred_element_type=jnp.float32)
     out_ref[...] += acc.astype(out_ref.dtype)
 
 
-def _unbind_kernel(s_ref, kext_ref, out_ref, *, T: int, R: int, D: int):
-    dt = pl.program_id(1)
-    jt = pl.program_id(2)
-    d0 = dt * T
-    j0 = jt * T
-
-    @pl.when(jt == 0)
+def _unbind_kernel(s_ref, toep_ref, out_ref, *, R: int):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    s = s_ref[...].astype(jnp.float32)            # (GT, T)
-    ia, ib = _window_indices(T)
-    widx = ia - ib + (T - 1)                      # toep[a, b] <- win[a - b + T - 1]
-    # Kext[w0 + (a - b + T-1)] == K[(j0+a - d0-b) mod D]
-    w0 = j0 - d0 + D - (T - 1)
-    outs = []
+    s = s_ref[...].astype(jnp.float32)            # (GT, T_j)
     for i in range(R):
-        win = jax.lax.dynamic_slice(kext_ref[i], (w0,), (2 * T - 1,))
-        toep = jnp.take(win, widx, axis=0)        # (T_j, T_d)
-        outs.append(jnp.dot(s, toep, preferred_element_type=jnp.float32))
-    acc = jnp.stack(outs, axis=1)                 # (GT, R, T)
-    out_ref[...] += acc.astype(out_ref.dtype)
+        # contract s's j axis with the block's row axis: s @ block^T
+        acc = jax.lax.dot_general(
+            s, toep_ref[i, 0].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)   # (GT, T_d)
+        out_ref[:, i, :] += acc.astype(out_ref.dtype)
 
 
 def _interpret() -> bool:
@@ -130,48 +125,59 @@ def execution_mode() -> str:
     return "pallas-interpret" if _interpret() else "pallas-compiled"
 
 
-@functools.partial(jax.jit, static_argnames=("tile",))
-def bind_superpose_kernel(Z: jax.Array, Kext: jax.Array, tile: int | None = None) -> jax.Array:
-    """Z (G, R, D), Kext (R, 2D) -> S (G, D).  Requires divisible tiles."""
-    G, R, D = Z.shape
-    assert Kext.shape == (R, 2 * D), (Kext.shape, (R, 2 * D))
+def _geometry(G: int, D: int, tile: int | None):
     T = tile or _pick_tile(D)
     _check_tile(D, T)
-    GT = _pick_tile(G, 8)
-    grid = (G // GT, D // T, D // T)
-    kernel = functools.partial(_bind_kernel, T=T, R=R, D=D)
+    # the (GT, T) output block needs GT % 8 == 0 or GT == G (TPU tiling)
+    GT = 8 if G % 8 == 0 else G
+    return T, GT, (G // GT, D // T, D // T)
+
+
+_PARAMS = dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+@functools.partial(jax.jit, static_argnames=("tile",))
+def bind_superpose_kernel(Z: jax.Array, K: jax.Array, tile: int | None = None) -> jax.Array:
+    """Z (G, R, D), keys K (R, D) -> S (G, D).  Requires divisible tiles."""
+    G, R, D = Z.shape
+    T, GT, grid = _geometry(G, D, tile)
+    if K.shape != (R, D):
+        raise ValueError(f"keys {K.shape} do not match Z's (R, D) = {(R, D)}")
+    nT = D // T
     return pl.pallas_call(
-        kernel,
+        functools.partial(_bind_kernel, R=R),
         grid=grid,
         in_specs=[
             pl.BlockSpec((GT, R, T), lambda g, dt, jt: (g, 0, jt)),
-            pl.BlockSpec((R, 2 * D), lambda g, dt, jt: (0, 0)),
+            pl.BlockSpec((R, 1, T, T),
+                         lambda g, dt, jt: (0, (dt - jt + nT) % nT, 0, 0)),
         ],
         out_specs=pl.BlockSpec((GT, T), lambda g, dt, jt: (g, dt)),
         out_shape=jax.ShapeDtypeStruct((G, D), Z.dtype),
+        compiler_params=pltpu.CompilerParams(**_PARAMS),
         interpret=_interpret(),
-    )(Z, Kext)
+    )(Z, toeplitz_blocks(K, T))
 
 
 @functools.partial(jax.jit, static_argnames=("tile",))
-def unbind_kernel(S: jax.Array, Kext: jax.Array, tile: int | None = None) -> jax.Array:
-    """S (G, D), Kext (R, 2D) -> Zhat (G, R, D).  Requires divisible tiles."""
+def unbind_kernel(S: jax.Array, K: jax.Array, tile: int | None = None) -> jax.Array:
+    """S (G, D), keys K (R, D) -> Zhat (G, R, D).  Requires divisible tiles."""
     G, D = S.shape
-    R = Kext.shape[0]
-    assert Kext.shape == (R, 2 * D)
-    T = tile or _pick_tile(D)
-    _check_tile(D, T)
-    GT = _pick_tile(G, 8)
-    grid = (G // GT, D // T, D // T)
-    kernel = functools.partial(_unbind_kernel, T=T, R=R, D=D)
+    R = K.shape[0]
+    T, GT, grid = _geometry(G, D, tile)
+    if K.shape != (R, D):
+        raise ValueError(f"keys {K.shape} do not match S's D = {D}")
+    nT = D // T
     return pl.pallas_call(
-        kernel,
+        functools.partial(_unbind_kernel, R=R),
         grid=grid,
         in_specs=[
             pl.BlockSpec((GT, T), lambda g, dt, jt: (g, jt)),
-            pl.BlockSpec((R, 2 * D), lambda g, dt, jt: (0, 0)),
+            pl.BlockSpec((R, 1, T, T),
+                         lambda g, dt, jt: (0, (jt - dt + nT) % nT, 0, 0)),
         ],
         out_specs=pl.BlockSpec((GT, R, T), lambda g, dt, jt: (g, 0, dt)),
         out_shape=jax.ShapeDtypeStruct((G, R, D), S.dtype),
+        compiler_params=pltpu.CompilerParams(**_PARAMS),
         interpret=_interpret(),
-    )(S, Kext)
+    )(S, toeplitz_blocks(K, T))

@@ -1,31 +1,25 @@
 """Jit'd public wrappers over the Pallas HRR kernels.
 
-Adds shape checks, the doubled-key layout, and custom VJPs.  The codec is
-linear in Z, and its adjoints are again HRR ops with the SAME keys:
+Adds custom VJPs over the kernels (which precompute the keys' Toeplitz
+blocks themselves).  The codec is linear in Z, and its adjoints are again HRR ops with the SAME keys:
 
     d/dZ of bind_superpose  == unbind        (correlate the upstream grad)
     d/dS of unbind          == bind_superpose (bind+superpose the upstream grad)
 
 which is exactly how C3-SL compresses the backward-path gradients with zero
-extra machinery.  Keys are constants (stop_gradient; no key cotangent).
+extra machinery.  Keys are constants: the VJPs return no key cotangent.
 """
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import circconv
-
-
-def _kext(K: jax.Array) -> jax.Array:
-    K = jax.lax.stop_gradient(K)
-    return jnp.concatenate([K, K], axis=-1)
 
 
 @jax.custom_vjp
 def bind_superpose_pallas(Z: jax.Array, K: jax.Array) -> jax.Array:
     """Z (G, R, D), K (R, D) -> S (G, D) via the Pallas Toeplitz kernel."""
-    return circconv.bind_superpose_kernel(Z, _kext(K))
+    return circconv.bind_superpose_kernel(Z, K)
 
 
 def _bind_fwd(Z, K):
@@ -33,7 +27,7 @@ def _bind_fwd(Z, K):
 
 
 def _bind_bwd(K, dS):
-    dZ = circconv.unbind_kernel(dS, _kext(K))
+    dZ = circconv.unbind_kernel(dS, K)
     return dZ, None
 
 
@@ -43,7 +37,7 @@ bind_superpose_pallas.defvjp(_bind_fwd, _bind_bwd)
 @jax.custom_vjp
 def unbind_pallas(S: jax.Array, K: jax.Array) -> jax.Array:
     """S (G, D), K (R, D) -> Zhat (G, R, D) via the Pallas Toeplitz kernel."""
-    return circconv.unbind_kernel(S, _kext(K))
+    return circconv.unbind_kernel(S, K)
 
 
 def _unbind_fwd(S, K):
@@ -51,7 +45,7 @@ def _unbind_fwd(S, K):
 
 
 def _unbind_bwd(K, dZhat):
-    dS = circconv.bind_superpose_kernel(dZhat, _kext(K))
+    dS = circconv.bind_superpose_kernel(dZhat, K)
     return dS, None
 
 
@@ -63,15 +57,14 @@ unbind_pallas.defvjp(_unbind_fwd, _unbind_bwd)
 # ---------------------------------------------------------------------------
 
 def paged_attention_decode(q, cache, table, pos, *, length: int,
-                           sliding_window=None, compute_dtype=None,
-                           interpret=None):
+                           sliding_window=None, compute_dtype=None):
     """Decode-step attention over paged KV pools, page-table walk in-kernel.
 
     ``q`` (B, 1, H, hd) post-rope; ``cache`` the attn sublayer's pool dict
     ({"k", "v"} float pools, plus {"k_scale", "v_scale"} when int8-
     quantized); ``table`` (B, P) int32 page table; ``pos`` (B,) int32
     per-slot positions.  Returns (B, 1, H*hd), bit-identical to
-    ``_sdpa[_quant]`` over ``gather_pages`` of the same pools.
+    ``attention.sdpa_decode`` over ``gather_pages`` of the same pools.
 
     Inference-only (no custom VJP): decode never differentiates through
     the cache read.  Quantized vs float dispatch mirrors
@@ -82,7 +75,6 @@ def paged_attention_decode(q, cache, table, pos, *, length: int,
         return pa.paged_attention_quant(
             q, cache["k"], cache["k_scale"], cache["v"], cache["v_scale"],
             table, pos, length=length, sliding_window=sliding_window,
-            compute_dtype=compute_dtype, interpret=interpret)
+            compute_dtype=compute_dtype)
     return pa.paged_attention(q, cache["k"], cache["v"], table, pos,
-                              length=length, sliding_window=sliding_window,
-                              interpret=interpret)
+                              length=length, sliding_window=sliding_window)

@@ -10,17 +10,15 @@ map of each pool operand is ``table[b, p]`` — the pages stream
 HBM -> VMEM directly in page-table order, and the contiguous view never
 exists (vLLM's PagedAttention, expressed in Pallas).
 
-Bit-identical equivalence with the gather path is the design constraint
-(the serving suite pins greedy outputs, not tolerances), so the reduction
-is NOT a flash-style online softmax: once a slot's pages sit in VMEM
-scratch, the kernel runs the literal op sequence of
-``repro.models.attention._sdpa`` / ``_sdpa_quant`` — same einsum strings
-with B=1/Sq=1 singleton axes, same f32 casts, same ``hd ** -0.5``
-placement, same ``NEG_INF`` masking, same ``jax.nn.softmax`` — on the
-same values the gathered view would hold.  Decode-step VMEM comfortably
-fits the whole per-slot K/V strip (see kernels/README.md for the budget),
-so tiling the T axis buys nothing at these shapes and would cost the
-bitwise guarantee.
+Bit-identical equivalence with the gather path (under interpret mode) is
+the design constraint — the serving suite pins greedy outputs, not
+tolerances — so the reduction is NOT a flash-style online softmax: once
+a slot's pages sit in a head-major VMEM strip, the kernel calls
+``repro.models.attention.attend_slot`` / ``attend_slot_quant``, the same
+per-slot function the gather read vmaps over slots.  Decode-step VMEM
+holds the whole per-slot K/V strip up to T=2048 at KV=32 in f32 (see
+kernels/README.md for the budget), so tiling the T axis would cost the
+bitwise guarantee for nothing at the serving engine's shapes.
 
 Coverage: GQA/MHA decode (linear caches and ring-buffer SWA) with float
 or int8-quantized KV pools.  MLA latent caches and prefill stay on the
@@ -40,88 +38,58 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.circconv import _interpret
+from repro.kernels import circconv
+from repro.models import attention as attn_lib
 
-# Must match repro.models.attention.NEG_INF or masked scores differ bitwise.
-NEG_INF = -1e30
-
-
-def _decode_mask(pos_b, T: int, sliding_window):
-    """The (1, 1, 1, T) decode validity mask for one slot — the literal
-    mask math of ``apply_gqa_decode`` at B=1 (linear: written positions;
-    ring: the last min(pos+1, T) writes)."""
-    idx = jnp.arange(T)[None, :]
-    if sliding_window is not None:
-        slots = pos_b % T
-        age = (slots[:, None] - idx) % T
-        valid = age < jnp.minimum(pos_b + 1, T)[:, None]
-    else:
-        valid = idx <= pos_b[:, None]
-    return valid[:, None, None, :]
+def _land(acc, page, p, ps):
+    """Append one (ps, KV, hd) page to the slot's head-major (KV, P*ps, hd)
+    strip, so the compute step runs leading-batch dots over heads."""
+    acc[:, pl.ds(p * ps, ps), :] = jnp.swapaxes(page, 0, 1)
 
 
 def _attn_kernel(table_ref, pos_ref, q_ref, k_pool_ref, v_pool_ref, out_ref,
-                 k_acc, v_acc, *, T: int, ps: int, P: int, H: int, KV: int,
-                 hd: int, sliding_window):
+                 k_acc, v_acc, *, T: int, ps: int, P: int, sliding_window):
     """Float-KV body.  Grid (B, P): step (b, p) lands page table[b, p] in
     VMEM via the block index map and appends it to the slot's scratch
-    strip; the last page step runs the full ``_sdpa`` op sequence."""
+    strip; the last page step runs ``attend_slot`` over the strip."""
     b = pl.program_id(0)
     p = pl.program_id(1)
-    k_acc[pl.ds(p * ps, ps)] = k_pool_ref[0]
-    v_acc[pl.ds(p * ps, ps)] = v_pool_ref[0]
+    _land(k_acc, k_pool_ref[0], p, ps)
+    _land(v_acc, v_pool_ref[0], p, ps)
 
     @pl.when(p == P - 1)
     def _compute():
-        q = q_ref[...].reshape(1, 1, H, hd)
-        k = k_acc[...][:T][None]                       # (1, T, KV, hd)
-        v = v_acc[...][:T][None]
-        pos_b = pos_ref[b][None]
-        mask = _decode_mask(pos_b, T, sliding_window)
-        groups = H // KV
-        qg = q.reshape(1, 1, KV, groups, hd)
-        scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k).astype(jnp.float32)
-        scores = scores * (hd ** -0.5)
-        scores = jnp.where(mask[:, :, None, :, :], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v)
-        out_ref[0] = out.reshape(H * hd)
+        valid = attn_lib.decode_valid(pos_ref[b], T, sliding_window)
+        out_ref[0] = attn_lib.attend_slot(q_ref[0], k_acc[:, :T],
+                                          v_acc[:, :T], valid)
 
 
 def _attn_kernel_quant(table_ref, pos_ref, q_ref, k_pool_ref, ks_pool_ref,
                        v_pool_ref, vs_pool_ref, out_ref, k_acc, ks_acc,
-                       v_acc, vs_acc, *, T: int, ps: int, P: int, H: int,
-                       KV: int, hd: int, sliding_window, compute_dtype):
-    """int8-KV body: pages stream as int8 + per-(pos, kv-head) scales, and
-    the compute step is the literal ``_sdpa_quant`` sequence (scales folded
-    into scores/probs; the dequantized cache is never materialized)."""
+                       v_acc, vs_acc, *, T: int, ps: int, P: int,
+                       sliding_window, compute_dtype):
+    """int8-KV body: pages stream as int8 + per-(pos, kv-head) scales and
+    the last page step runs ``attend_slot_quant`` (the dequantized cache
+    is never materialized).  Scales land lane-dense as (P, ps, KV): a
+    (P*ps, KV, 1) strip would pad its last dim to 128 lanes."""
     b = pl.program_id(0)
     p = pl.program_id(1)
-    k_acc[pl.ds(p * ps, ps)] = k_pool_ref[0]
-    v_acc[pl.ds(p * ps, ps)] = v_pool_ref[0]
-    ks_acc[pl.ds(p * ps, ps)] = ks_pool_ref[0]
-    vs_acc[pl.ds(p * ps, ps)] = vs_pool_ref[0]
+    _land(k_acc, k_pool_ref[0], p, ps)
+    _land(v_acc, v_pool_ref[0], p, ps)
+    ks_acc[p] = ks_pool_ref[0, :, :, 0]
+    vs_acc[p] = vs_pool_ref[0, :, :, 0]
 
     @pl.when(p == P - 1)
     def _compute():
-        q = q_ref[...].reshape(1, 1, H, hd)
-        k_q = k_acc[...][:T][None]
-        v_q = v_acc[...][:T][None]
-        k_scale = ks_acc[...][:T][None]                # (1, T, KV, 1)
-        v_scale = vs_acc[...][:T][None]
-        pos_b = pos_ref[b][None]
-        mask = _decode_mask(pos_b, T, sliding_window)
-        groups = H // KV
-        qg = q.reshape(1, 1, KV, groups, hd)
-        scores = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.float32),
-                            k_q.astype(jnp.float32))
-        scores = scores * k_scale[:, :, :, 0].transpose(0, 2, 1)[:, :, None, None, :]
-        scores = scores * (hd ** -0.5)
-        scores = jnp.where(mask[:, :, None, :, :], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        probs = probs * v_scale[:, :, :, 0].transpose(0, 2, 1)[:, :, None, None, :]
-        out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v_q.astype(jnp.float32))
-        out_ref[0] = out.reshape(H * hd).astype(compute_dtype)
+        KV = ks_acc.shape[-1]
+
+        def head_major(acc):                 # (P, ps, KV) -> (KV, T)
+            return acc[...].reshape(P * ps, KV)[:T].T
+
+        valid = attn_lib.decode_valid(pos_ref[b], T, sliding_window)
+        out_ref[0] = attn_lib.attend_slot_quant(
+            q_ref[0], k_acc[:, :T], head_major(ks_acc), v_acc[:, :T],
+            head_major(vs_acc), valid, compute_dtype)
 
 
 def _check_geometry(q, pool, table, length):
@@ -139,72 +107,106 @@ def _check_geometry(q, pool, table, length):
     return B, H, hd, P, ps, KV
 
 
+def _padded_bytes(shape, dtype):
+    """VMEM bytes of a block: the last two dims pad to the (sublane, 128)
+    tile, sublanes to 32 bytes' worth of rows (8 f32, 16 bf16, 32 int8)."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    sub = 32 // item
+    n = -(-rows // sub) * sub * (-(-lanes // 128) * 128) * item
+    for d in lead:
+        n *= d
+    return n
+
+
+# v5e and later hold 128 MiB of VMEM per core; the default scoped limit
+# (16 MiB on v5e) is below one slot's K+V strip at deepseek-7b widths.
+_VMEM_CAP = 100 * 2 ** 20
+
+
+def _compiler_params(blocks, scratch, temps):
+    """Double-buffered blocks + scratch + the compute step's f32 values,
+    with 25% headroom for Mosaic's own stack."""
+    need = (2 * sum(_padded_bytes(*b) for b in blocks)
+            + sum(_padded_bytes(*s) for s in scratch)
+            + sum(_padded_bytes(*t) for t in temps))
+    limit = min(max(need + need // 4, 16 * 2 ** 20), _VMEM_CAP)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=limit)
+
+
 def paged_attention(q, k_pool, v_pool, table, pos, *, length: int,
-                    sliding_window=None, interpret=None):
+                    sliding_window=None):
     """q (B, 1, H, hd) post-rope; k/v pools (num_pages, ps, KV, hd); table
     (B, P) int32; pos (B,) int32.  Returns the (B, 1, H*hd) attention
-    output — bit-identical to ``_sdpa(q, *gather_pages(...), mask)``."""
+    output — ``attention.sdpa_decode`` over ``gather_pages`` of the same
+    pools, computed in-kernel."""
     B, H, hd, P, ps, KV = _check_geometry(q, k_pool, table, length)
+    G = H // KV
+    page = (ps, KV, hd)
+    qspec = pl.BlockSpec((1, KV, G, hd), lambda b, p, tab, pos: (b, 0, 0, 0))
+    pspec = pl.BlockSpec((1,) + page, lambda b, p, tab, pos: (tab[b, p], 0, 0, 0))
+    strip = (KV, P * ps, hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, H * hd), lambda b, p, tab, pos: (b, 0)),
-            pl.BlockSpec((1, ps, KV, hd),
-                         lambda b, p, tab, pos: (tab[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, ps, KV, hd),
-                         lambda b, p, tab, pos: (tab[b, p], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H * hd), lambda b, p, tab, pos: (b, 0)),
-        scratch_shapes=[pltpu.VMEM((P * ps, KV, hd), k_pool.dtype),
-                        pltpu.VMEM((P * ps, KV, hd), v_pool.dtype)],
+        in_specs=[qspec, pspec, pspec],
+        out_specs=qspec,
+        scratch_shapes=[pltpu.VMEM(strip, k_pool.dtype),
+                        pltpu.VMEM(strip, v_pool.dtype)],
     )
-    kernel = functools.partial(_attn_kernel, T=length, ps=ps, P=P, H=H,
-                               KV=KV, hd=hd, sliding_window=sliding_window)
+    kernel = functools.partial(_attn_kernel, T=length, ps=ps, P=P,
+                               sliding_window=sliding_window)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H * hd), q.dtype),
-        interpret=_interpret() if interpret is None else interpret,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        compiler_params=_compiler_params(
+            blocks=[((KV, G, hd), q.dtype)] * 2 + [(page, k_pool.dtype)] * 2,
+            scratch=[(strip, k_pool.dtype)] * 2,
+            temps=[((KV, length, hd), jnp.float32)] * 2),
+        interpret=circconv._interpret(),
     )(table.astype(jnp.int32), pos.astype(jnp.int32),
-      q.reshape(B, H * hd), k_pool, v_pool)
+      q.reshape(B, KV, G, hd), k_pool, v_pool)
     return out.reshape(B, 1, H * hd)
 
 
 def paged_attention_quant(q, k_pool, k_scale_pool, v_pool, v_scale_pool,
                           table, pos, *, length: int, sliding_window=None,
-                          compute_dtype=None, interpret=None):
+                          compute_dtype=None):
     """int8-KV variant: scale pools (num_pages, ps, KV, 1) ride the same
-    page table.  Bit-identical to ``_sdpa_quant`` over the gathered view."""
+    page table.  The int8 ``attention.sdpa_decode``, computed in-kernel."""
     B, H, hd, P, ps, KV = _check_geometry(q, k_pool, table, length)
+    G = H // KV
     compute_dtype = compute_dtype or q.dtype
+    page, spage = (ps, KV, hd), (ps, KV, 1)
+    qspec = pl.BlockSpec((1, KV, G, hd), lambda b, p, tab, pos: (b, 0, 0, 0))
+    pspec = pl.BlockSpec((1,) + page, lambda b, p, tab, pos: (tab[b, p], 0, 0, 0))
+    sspec = pl.BlockSpec((1,) + spage, lambda b, p, tab, pos: (tab[b, p], 0, 0, 0))
+    strip, sstrip = (KV, P * ps, hd), (P, ps, KV)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, H * hd), lambda b, p, tab, pos: (b, 0)),
-            pl.BlockSpec((1, ps, KV, hd),
-                         lambda b, p, tab, pos: (tab[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, ps, KV, 1),
-                         lambda b, p, tab, pos: (tab[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, ps, KV, hd),
-                         lambda b, p, tab, pos: (tab[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, ps, KV, 1),
-                         lambda b, p, tab, pos: (tab[b, p], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H * hd), lambda b, p, tab, pos: (b, 0)),
-        scratch_shapes=[pltpu.VMEM((P * ps, KV, hd), k_pool.dtype),
-                        pltpu.VMEM((P * ps, KV, 1), k_scale_pool.dtype),
-                        pltpu.VMEM((P * ps, KV, hd), v_pool.dtype),
-                        pltpu.VMEM((P * ps, KV, 1), v_scale_pool.dtype)],
+        in_specs=[qspec, pspec, sspec, pspec, sspec],
+        out_specs=qspec,
+        scratch_shapes=[pltpu.VMEM(strip, k_pool.dtype),
+                        pltpu.VMEM(sstrip, k_scale_pool.dtype),
+                        pltpu.VMEM(strip, v_pool.dtype),
+                        pltpu.VMEM(sstrip, v_scale_pool.dtype)],
     )
     kernel = functools.partial(_attn_kernel_quant, T=length, ps=ps, P=P,
-                               H=H, KV=KV, hd=hd,
                                sliding_window=sliding_window,
                                compute_dtype=compute_dtype)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H * hd), compute_dtype),
-        interpret=_interpret() if interpret is None else interpret,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), compute_dtype),
+        compiler_params=_compiler_params(
+            blocks=[((KV, G, hd), q.dtype)] * 2 + [(page, k_pool.dtype)] * 2
+            + [(spage, k_scale_pool.dtype)] * 2,
+            scratch=[(strip, k_pool.dtype)] * 2
+            + [(sstrip, k_scale_pool.dtype)] * 2,
+            temps=[((KV, length, hd), jnp.float32)] * 2),
+        interpret=circconv._interpret(),
     )(table.astype(jnp.int32), pos.astype(jnp.int32),
-      q.reshape(B, H * hd), k_pool, k_scale_pool, v_pool, v_scale_pool)
+      q.reshape(B, KV, G, hd), k_pool, k_scale_pool, v_pool, v_scale_pool)
     return out.reshape(B, 1, H * hd)

@@ -179,7 +179,7 @@ def _lower_and_compile(cfg, shape_name, mesh, codec, codec_params,
     batch_sh = sh.batch_shardings(batch, mesh)
     repl = NamedSharding(mesh, P())
 
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if spec["kind"] == "train":
             opt, train_step = build_train_step(cfg, codec, codec_params,
                                                num_microbatches)
@@ -403,7 +403,7 @@ def pipeline_dryrun(arch: str, *, R: int = 4, quant_bits=None, unitary=False,
     def grad_step(params, batch):
         return jax.value_and_grad(loss_fn)(params, batch)
 
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(grad_step, in_shardings=(param_sh, batch_sh)).lower(
             params, batch)
         compiled = lowered.compile()

@@ -60,10 +60,10 @@ class CompStats:
     children: list = dataclasses.field(default_factory=list)
 
 
-# TopK lowerings this parser recognizes: the XLA custom-call (CPU/GPU) and
-# the first-class `topk(...)` HLO op (newer XLA).  Both produce a
-# (values[rows, k], indices[rows, k]) tuple from an operand [rows, D].
-_TOPK_RE = re.compile(r'custom_call_target="TopK"|\btopk\(')
+# XLA's TopK custom call: a (values[rows, k], indices[rows, k]) tuple
+# from an operand [rows, D].  Operands print as bare names ("%abs.40").
+_TOPK_RE = re.compile(r'custom_call_target="TopK"')
+_OPERAND_RE = re.compile(r"\s*%?([\w.\-]+)")
 
 
 def _is_magnitude_topk(opname: str, defs: dict, comps: dict | None) -> bool:
@@ -84,7 +84,7 @@ def _is_magnitude_topk(opname: str, defs: dict, comps: dict | None) -> bool:
     return False
 
 
-def _topk_wire_bytes_for_line(ln: str, defs: dict | None = None,
+def _topk_wire_bytes_for_line(ln: str, defs: dict,
                               comps: dict | None = None) -> float:
     """MEASURED wire bytes of one top-k op's mask-encoded payload.
 
@@ -97,22 +97,16 @@ def _topk_wire_bytes_for_line(ln: str, defs: dict | None = None,
     walk, like every other per-computation stat).  Only MAGNITUDE top-ks
     (operand resolving to ``abs``, see :func:`_is_magnitude_topk`) count
     when ``defs`` is given — a router's top-k over raw logits is program
-    control flow, not payload.  Operands print with inline types or as
-    bare names depending on the HLO printer version (same dialect split
-    ``_dot_flops`` handles); ``defs`` doubles as the shape fallback.
+    control flow, not payload.  The operand's shape comes from ``defs``.
     """
     if not _TOPK_RE.search(ln):
         return 0.0
-    call = "custom-call(" if "custom-call(" in ln else "topk("
-    left, _, right = ln.partition(call)
+    left, _, right = ln.partition("custom-call(")
     outs = _SHAPE_RE.findall(left)
-    opnd = _SHAPE_RE.search(right)
-    nm = re.match(r"\s*(?:\w+\[[\d,]*\]\S*\s+)?%?([\w.\-]+)", right)
-    if defs is not None:
-        if nm is None or not _is_magnitude_topk(nm.group(1), defs, comps):
-            return 0.0
-        if opnd is None:
-            opnd = _SHAPE_RE.search(defs.get(nm.group(1), ""))
+    nm = _OPERAND_RE.match(right)
+    if nm is None or not _is_magnitude_topk(nm.group(1), defs, comps):
+        return 0.0
+    opnd = _SHAPE_RE.search(defs.get(nm.group(1), ""))
     if not outs or not opnd:
         return 0.0
     val_dims = [int(d) for d in outs[0][1].split(",") if d.strip()]
@@ -235,13 +229,12 @@ def _build_shape_map(comps) -> dict[str, str]:
 
 
 def _dot_flops(line: str, out_shape_text: str, shapes: dict[str, str]) -> float:
-    # operand lists print either bare names ("dot(%a, %b)") or with inline
-    # types ("dot(f32[64,128]{1,0} %a, ...)") depending on the HLO printer
-    # version — take the inline shape when present, else look the name up
-    m = re.search(r"dot\(\s*(?:(\w+\[[\d,]*\]\S*)\s+)?%?([\w.\-]+)", line)
-    if not m:
+    # operands print as bare names ("dot(%a, %b)"): look the lhs shape up
+    _, _, right = line.partition("dot(")
+    m = _OPERAND_RE.match(right)
+    if not right or not m:
         return 0.0
-    lhs = m.group(1) or shapes.get(m.group(2), "")
+    lhs = shapes.get(m.group(1), "")
     lhs_m = _SHAPE_RE.search(lhs)
     out_m = _SHAPE_RE.search(out_shape_text)
     if not lhs_m or not out_m:

@@ -7,16 +7,10 @@ Multi-pod:  (pod=2, data=16, model=16) = 512 chips.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5; older CPU-only installs can still import mesh-free paths
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
@@ -31,15 +25,6 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: int | None = None):
     if pod:
         return _mesh((pod, data, model), ("pod", "data", "model"))
     return _mesh((data, model), ("data", "model"))
-
-
-def set_mesh(mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` on current jax,
-    the ``Mesh`` object's own context on older releases (which predate
-    ``jax.set_mesh`` but activate the mesh the same way for jit/shard_map)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
 
 
 # TPU v5e hardware constants for the roofline model (per chip)

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro import codecs, transport
 from repro.configs.base import get_config, reduced
+from repro.launch.runtime import configure_jax
 from repro.models import lm as lm_lib
 
 
@@ -285,6 +286,7 @@ def main():
                          "raises out of the serving loop) and event-loop "
                          "stall diagnostics on the front door")
     args = ap.parse_args()
+    configure_jax()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -34,6 +34,7 @@ from repro.checkpoint import save_checkpoint
 from repro.configs.base import get_config, reduced
 from repro.data.pipeline import SyntheticTokenDataset, make_batch_iterator
 from repro.launch import mesh as mesh_lib
+from repro.launch.runtime import configure_jax
 from repro.models import lm as lm_lib
 from repro.optim import adamw, apply_updates, clip_by_global_norm
 from repro.transport import pipeline as pipeline_lib
@@ -318,7 +319,7 @@ def run_pipeline(args, cfg):
     it = make_batch_iterator(data, args.batch)
     losses = []
     t0 = time.time()
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for step in range(args.steps):
             b = next(it)
             batch = {"x": b["tokens"], "y": b["labels"]}
@@ -388,6 +389,7 @@ def main():
                          "on loss/grad-norm; trades throughput for checks")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    configure_jax()
     if args.pipeline and (args.fault_drop > 0.0 or args.fault_corrupt > 0.0):
         raise SystemExit("fault injection drives the standard loop; the "
                          "pipeline path takes erasure masks through "

@@ -25,6 +25,8 @@ mid-prefill or empty; unmasked writes would stomp their pages).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -170,23 +172,71 @@ def _quantize_kv(x):
     return q, scale
 
 
-def _sdpa_quant(q, k_q, k_scale, v_q, v_scale, mask, compute_dtype):
-    """SDPA over an int8 cache: scales fold into scores/probs, so only the
-    int8 tensors stream from HBM."""
-    B, Sq, H, hd = q.shape
-    KV = k_q.shape[2]
-    groups = H // KV
-    qg = q.reshape(B, Sq, KV, groups, hd)
-    scores = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.float32),
-                        k_q.astype(jnp.float32))
-    scores = scores * k_scale[:, :, :, 0].transpose(0, 2, 1)[:, :, None, None, :]
+def decode_valid(pos, T: int, sliding_window=None):
+    """(1, T) validity of one slot's decode read at position ``pos``: a
+    linear cache sees positions <= pos; a ring buffer of length T sees its
+    last min(pos+1, T) writes.  A 2-D iota, so Mosaic lowers it too."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+    if sliding_window is not None:
+        age = (pos % T - idx) % T
+        return age < jnp.minimum(pos + 1, T)
+    return idx <= pos
+
+
+def attend_slot(q, k, v, valid):
+    """One slot's decode attention, head-major: q (KV, G, hd), k/v
+    (KV, T, hd), valid (1, T) -> (KV, G, hd).  The gather read vmaps it
+    over slots and the Pallas kernel runs it per slot, so both read paths
+    compute the same ops in the same order."""
+    hd = q.shape[-1]
+    scores = jnp.einsum("kgh,ksh->kgs", q, k,
+                        preferred_element_type=jnp.float32)
     scores = scores * (hd ** -0.5)
-    scores = jnp.where(mask[:, :, None, :, :] if mask.ndim == 4 else mask,
-                       scores, NEG_INF)
+    scores = jnp.where(valid[None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("kgs,ksh->kgh", probs, v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def attend_slot_quant(q, k_q, k_scale, v_q, v_scale, valid, compute_dtype):
+    """:func:`attend_slot` over an int8 cache, scales (KV, T): they fold
+    into scores/probs, so only the int8 tensors stream from HBM."""
+    hd = q.shape[-1]
+    scores = jnp.einsum("kgh,ksh->kgs", q.astype(jnp.float32),
+                        k_q.astype(jnp.float32))
+    scores = scores * k_scale[:, None, :]
+    scores = scores * (hd ** -0.5)
+    scores = jnp.where(valid[None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    probs = probs * v_scale[:, :, :, 0].transpose(0, 2, 1)[:, :, None, None, :]
-    out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v_q.astype(jnp.float32))
-    return out.reshape(B, Sq, H * hd).astype(compute_dtype)
+    probs = probs * v_scale[:, None, :]
+    out = jnp.einsum("kgs,ksh->kgh", probs, v_q.astype(jnp.float32))
+    return out.astype(compute_dtype)
+
+
+def sdpa_decode(q, view, pos, T: int, sliding_window=None,
+                compute_dtype=None):
+    """The gather read's decode attention: q (B, 1, H, hd) over the
+    (B, T, KV, ...) cache ``view``, slot b at position pos[b].  Returns
+    (B, 1, H*hd)."""
+    B, _, H, hd = q.shape
+    KV = view["k"].shape[2]
+    qh = q.reshape(B, KV, H // KV, hd)
+    valid = jax.vmap(lambda p: decode_valid(p, T, sliding_window))(pos)
+
+    def head_major(x):                 # (B, T, KV, ...) -> (B, KV, T, ...)
+        return jnp.swapaxes(x, 1, 2)
+
+    if "k_scale" in view:
+        attend = functools.partial(attend_slot_quant,
+                                   compute_dtype=compute_dtype or q.dtype)
+        out = jax.vmap(attend)(qh, head_major(view["k"]),
+                               head_major(view["k_scale"][..., 0]),
+                               head_major(view["v"]),
+                               head_major(view["v_scale"][..., 0]), valid)
+    else:
+        out = jax.vmap(attend_slot)(qh, head_major(view["k"]),
+                                    head_major(view["v"]), valid)
+    return out.reshape(B, 1, H * hd)
 
 
 def _per_row_update(cache_kv, new_kv, slots):
@@ -247,11 +297,11 @@ def apply_gqa_decode(p, x, cache, pos, *, num_heads, num_kv_heads, head_dim,
     Returns (y (B,1,D), new_cache).
 
     ``kv_read`` selects how a PAGED cache is read: ``"gather"``
-    materializes the contiguous view (paging.gather_pages) and reuses the
-    contiguous SDPA; ``"kernel"`` walks the page table inside the Pallas
+    materializes the contiguous view (paging.gather_pages) and reads it
+    with the contiguous layout's ``sdpa_decode``; ``"kernel"`` walks the page table inside the Pallas
     paged-attention kernel (repro.kernels.paged_attention) — no contiguous
-    gather, bit-identical outputs by construction (the kernel runs the
-    literal _sdpa/_sdpa_quant op sequence on the same values).
+    gather, bit-identical outputs by construction (the kernel runs
+    attend_slot / attend_slot_quant per slot on the same values).
     """
     B = x.shape[0]
     paged = pages is not None
@@ -287,20 +337,7 @@ def apply_gqa_decode(p, x, cache, pos, *, num_heads, num_kv_heads, head_dim,
                                           compute_dtype=x.dtype)
         return att @ p["w_o"], new_cache
     view = _view(new_cache, pages, T)
-    idx = jnp.arange(T)[None, :]
-    if sliding_window is not None:
-        # ring buffer: valid entries are the last min(pos+1, T) writes
-        age = (slots[:, None] - idx) % T
-        valid = age < jnp.minimum(pos_b + 1, T)[:, None]
-    else:
-        valid = idx <= pos_b[:, None]
-    mask = valid[:, None, None, :]
-    if quant:
-        y = _sdpa_quant(q, view["k"], view["k_scale"],
-                        view["v"], view["v_scale"], mask,
-                        x.dtype) @ p["w_o"]
-    else:
-        y = _sdpa(q, view["k"], view["v"], mask) @ p["w_o"]
+    y = sdpa_decode(q, view, pos_b, T, sliding_window, x.dtype) @ p["w_o"]
     return y, new_cache
 
 
@@ -347,7 +384,7 @@ def apply_gqa_prefill(p, x, cache, pos, valid, *, num_heads, num_kv_heads,
     quant = "k_scale" in cache
     if quant:
         # dequantized *view* for the prefill matmuls (transient, prefill-only;
-        # the decode hot loop keeps streaming int8 via _sdpa_quant)
+        # the decode hot loop keeps streaming int8 via attend_slot_quant)
         ck = (cview["k"].astype(jnp.float32) * cview["k_scale"]).astype(x.dtype)
         cv = (cview["v"].astype(jnp.float32) * cview["v_scale"]).astype(x.dtype)
     else:

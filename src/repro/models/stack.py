@@ -161,11 +161,8 @@ def _activation_constraint(h):
     per-layer *stored* copies (the remat scan carries) are sharded, or the
     88-layer models blow past HBM.  No-op outside a (data, model) mesh or on
     non-divisible shapes."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return h
-    if am is None or am.empty or h.ndim != 3:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or h.ndim != 3:
         return h
     from jax.sharding import AxisType, PartitionSpec as P
     # only axes still under automatic partitioning (inside shard_map some

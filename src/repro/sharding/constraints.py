@@ -12,11 +12,8 @@ import jax
 
 
 def constrain(x: jax.Array, template) -> jax.Array:
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return x
-    if am is None or am.empty:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return x
     from jax.sharding import AxisType, PartitionSpec as P
     auto = {n for n, t in zip(am.axis_names, am.axis_types)

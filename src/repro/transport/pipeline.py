@@ -46,21 +46,6 @@ from repro.transport.channel import grad_roundtrip, masked_decode
 from repro.transport.link import SplitLink
 
 
-def _shard_map(f, mesh, in_specs, out_specs, manual_axes):
-    """Partial-manual shard_map on current jax; full-manual fallback on
-    older releases (which lack ``jax.shard_map`` and whose partial-auto
-    mode cannot lower ``axis_index``).  The fallback replicates the
-    data/model-axis compute per device — correct, just not sharded —
-    so tests on simulated host meshes run everywhere."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(manual_axes),
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=frozenset())
-
-
 def _require_static(codec):
     chans = (codec.fwd.codec, codec.bwd.codec) if isinstance(codec, SplitLink) \
         else (codec,)
@@ -194,6 +179,8 @@ def make_pod_pipeline_loss_fn(
         if with_erasure:
             args += (keep,)
             specs += (P(),)
-        return _shard_map(inner, mesh, specs, P(), {"pod"})(*args)
+        # manual over "pod" only: data/model stay under automatic sharding
+        return jax.shard_map(inner, mesh=mesh, in_specs=specs, out_specs=P(),
+                             axis_names={"pod"}, check_vma=False)(*args)
 
     return loss

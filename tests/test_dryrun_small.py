@@ -43,7 +43,7 @@ def test_dryrun_reduced_arch_small_mesh():
         opt, train_step = dr.build_train_step(cfg, num_microbatches=2)
         opt_state = jax.eval_shape(opt.init, params)
         opt_sh = sh.opt_state_shardings(opt_state, mesh)
-        with mesh_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(train_step,
                               in_shardings=(param_sh, opt_sh, batch_sh),
                               out_shardings=(param_sh, opt_sh,

@@ -68,18 +68,17 @@ def test_header_param_order_handles_tuples():
 
 def test_topk_wire_bytes_from_custom_call_line():
     ln = ('%custom-call = (f32[20,8]{1,0}, s32[20,8]{1,0}) '
-          'custom-call(f32[20,64]{1,0} %abs.40), custom_call_target="TopK"')
-    defs = {"abs.40": "%abs.40 = f32[20,64]{1,0} abs(f32[20,64]{1,0} %x)"}
-    # 20 rows x (64-bit mask -> 8 bytes + 8 f32 survivors -> 32 bytes)
+          'custom-call(%abs.40), custom_call_target="TopK"')
+    defs = {"abs.40": "%abs.40 = f32[20,64]{1,0} abs(%x)"}
+    # 20 rows x (64-bit mask -> 8 bytes + 8 f32 survivors -> 32 bytes),
+    # the operand's shape resolved through the defs map
     assert hloparse._topk_wire_bytes_for_line(ln, defs) == 20 * (64 // 8 + 4 * 8)
-    # bare-name operand dialect: shape resolved through the defs map
-    bare = ('%custom-call = (f32[20,8]{1,0}, s32[20,8]{1,0}) '
-            'custom-call(%abs.40), custom_call_target="TopK"')
-    assert hloparse._topk_wire_bytes_for_line(bare, defs) \
-        == 20 * (64 // 8 + 4 * 8)
+    # a top-k of anything but |x| is not a wire payload
+    raw = dict(defs, **{"abs.40": "%abs.40 = f32[20,64]{1,0} add(%x, %y)"})
+    assert hloparse._topk_wire_bytes_for_line(ln, raw) == 0.0
     # non-topk custom calls measure nothing
     assert hloparse._topk_wire_bytes_for_line(
-        '%cc = f32[4]{0} custom-call(f32[4]{0} %x), '
+        '%cc = f32[4]{0} custom-call(%x), '
         'custom_call_target="Other"', defs) == 0.0
 
 
@@ -92,7 +91,7 @@ def test_topk_wire_bytes_excludes_router_topk():
 
     txt = jax.jit(lambda z: jax.lax.top_k(z, 2)).lower(
         jnp.zeros((64, 16))).compile().as_text()
-    assert "TopK" in txt or "topk(" in txt          # the op IS there
+    assert "TopK" in txt                            # the op IS there
     assert hloparse.analyze(txt)["topk_wire_bytes"] == 0.0
 
 

@@ -112,7 +112,8 @@ def test_trailing_page_parity_matches_sdpa_over_gather(length):
     """length = 16 sits EXACTLY on the page boundary (2 full pages of 8);
     17 is one-past (3rd page holds one row); 23 is a ragged tail.  The
     kernel fetches whole pages and slices scratch, gather slices the
-    reshaped view — both must agree bitwise, with the causal mask (not
+    reshaped view — both must agree bitwise with the gather read's decode
+    attention, with the causal mask (not
     the slice) hiding unwritten positions either way."""
     from repro.kernels import paged_attention as pa
     B, ps, H, KV, hd = 3, 8, 4, 2, 16
@@ -129,11 +130,9 @@ def test_trailing_page_parity_matches_sdpa_over_gather(length):
                                 max(length - ps - 1, 0)], np.int32))
     got = pa.paged_attention(q, k_pool, v_pool, table, pos, length=length)
 
-    k = gather_pages(k_pool, table, length)[None]      # (1, B, T, KV, hd)
-    v = gather_pages(v_pool, table, length)[None]
-    idx = jnp.arange(length)[None, :]
-    mask = (idx <= pos[:, None])[:, None, None, :]
-    want = attn_lib._sdpa(q, k[0], v[0], mask)
+    view = {"k": gather_pages(k_pool, table, length),   # (B, T, KV, hd)
+            "v": gather_pages(v_pool, table, length)}
+    want = attn_lib.sdpa_decode(q, view, pos, length)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -164,11 +163,9 @@ def test_page_walk_addressing_property():
         q = jnp.asarray(rng.randn(B, 1, H, hd).astype(np.float32))
         pos = jnp.asarray(rng.randint(0, length, (B,)).astype(np.int32))
         got = pa.paged_attention(q, k_pool, v_pool, table, pos, length=length)
-        k = gather_pages(k_pool, table, length)
-        v = gather_pages(v_pool, table, length)
-        idx = jnp.arange(length)[None, :]
-        mask = (idx <= pos[:, None])[:, None, None, :]
-        want = attn_lib._sdpa(q, k, v, mask)
+        view = {"k": gather_pages(k_pool, table, length),
+                "v": gather_pages(v_pool, table, length)}
+        want = attn_lib.sdpa_decode(q, view, pos, length)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     run()

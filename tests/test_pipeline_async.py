@@ -54,7 +54,7 @@ COMMON = textwrap.dedent("""
         lf = transport.make_pod_pipeline_loss_fn(
             embed_fn, stage_fn, head_loss_fn, codec, mesh,
             num_microbatches=M, async_depth=depth)
-        with mesh_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jax.jit(jax.value_and_grad(lf))(params, batch)
 
     def leaves_equal(a, b):
@@ -126,7 +126,7 @@ def test_depth_adds_exactly_one_bubble_step_per_unit():
             lf = transport.make_pod_pipeline_loss_fn(
                 embed_fn, stage_fn, head_loss_fn, codec, mesh,
                 num_microbatches=M, async_depth=depth)
-            with mesh_lib.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 compiled = jax.jit(lf).lower(params, batch).compile()
             a = hloparse.analyze(compiled.as_text())
             return a["coll_by_op"].get("collective-permute", 0.0)

@@ -55,7 +55,7 @@ PIPELINE_PROG = textwrap.dedent("""
 
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
     batch = {{"x": tokens, "y": tokens}}
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params, batch)
         gnorm = sum(float(jnp.sum(jnp.abs(g))) for g in jax.tree.leaves(grads))
 
