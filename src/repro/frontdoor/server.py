@@ -60,6 +60,7 @@ import itertools
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import codecs as codecs_lib
 from repro.faults import ChannelErasure
@@ -267,14 +268,15 @@ class FrontDoorServer:
 
     async def _pump(self) -> bool:
         """One engine tick plus result delivery; True if anything moved."""
-        eng = self.engine
-        worked = False
-        if eng.queue or eng.active:
-            worked = eng.tick()
-        worked |= await self._stream_tokens()
-        worked |= await self._deliver()
-        self._sweep_expired()
-        return worked
+        with TraceAnnotation("frontdoor.pump"):
+            eng = self.engine
+            worked = False
+            if eng.queue or eng.active:
+                worked = eng.tick()
+            worked |= await self._stream_tokens()
+            worked |= await self._deliver()
+            self._sweep_expired()
+            return worked
 
     async def _stream_tokens(self) -> bool:
         """Forward the engine's incremental token bursts as TOKENS frames.
@@ -288,70 +290,73 @@ class FrontDoorServer:
         events = self.engine.pop_stream_events()
         if not events:
             return False
-        for uid, start, tokens in events:
-            route = self._routes.get(uid)
-            if route is None:
-                continue                      # not ours (direct submit)
-            conn = route.sess.conn
-            if conn is None or not conn.open:
-                continue
-            header = {"rid": route.rid, "off": start, "n": len(tokens)}
-            arr_header, payload = proto.pack_array(
-                np.asarray(tokens, dtype=np.int32))
-            header.update(arr_header)
-            try:
-                sent = await conn.stream.send(MsgType.TOKENS, header,
-                                              payload)
-                self.qos.tenant(route.tenant).bytes_out += sent
-            except (ConnectionError, RuntimeError, OSError):
-                conn.open = False
+        with TraceAnnotation("frontdoor.stream_tokens"):
+            for uid, start, tokens in events:
+                route = self._routes.get(uid)
+                if route is None:
+                    continue                  # not ours (direct submit)
+                conn = route.sess.conn
+                if conn is None or not conn.open:
+                    continue
+                header = {"rid": route.rid, "off": start, "n": len(tokens)}
+                arr_header, payload = proto.pack_array(
+                    np.asarray(tokens, dtype=np.int32))
+                header.update(arr_header)
+                try:
+                    sent = await conn.stream.send(MsgType.TOKENS, header,
+                                                  payload)
+                    self.qos.tenant(route.tenant).bytes_out += sent
+                except (ConnectionError, RuntimeError, OSError):
+                    conn.open = False
         return True
 
     async def _deliver(self) -> bool:
         eng = self.engine
         if not eng.finished:
             return False
-        finished, eng.finished = list(eng.finished), []
-        now = time.monotonic()
-        for req in finished:
-            route = self._routes.pop(req.uid, None)
-            if route is None:
-                continue                      # not ours (direct submit)
-            self.admission.release(route.tenant)
-            tq = self.qos.tenant(route.tenant)
-            ttft = (req.t_first - req.t_submit
-                    if req.t_first is not None else None)
-            decode_s = (now - req.t_first) if req.t_first is not None else 0.0
-            ttlt = now - req.t_submit
-            header = {"rid": route.rid, "ttft_s": ttft, "ttlt_s": ttlt,
-                      "evictions": req.evictions,
-                      "accepted": req.accepted, "rejected": req.rejected,
-                      "rollbacks": req.rollbacks}
-            arr_header, payload = proto.pack_array(
-                np.asarray(req.out, dtype=np.int32))
-            header.update(arr_header)
-            sent = 0
-            conn = route.sess.conn
-            delivered = False
-            if conn is not None and conn.open:
-                try:
-                    sent = await conn.stream.send(MsgType.RESULT, header,
-                                                  payload)
-                    tq.bytes_out += sent
-                    delivered = True
-                except (ConnectionError, RuntimeError, OSError):
-                    conn.open = False
-            if delivered:
-                route.sess.mark_delivered(route.rid)
-            else:
-                # park for a reattach — the session keeps the result until
-                # the client resumes or the resume TTL sweeps it
-                route.sess.parked.append((route.rid, header, payload))
-            tq.record_result(ttft_s=ttft, gen_tokens=len(req.out),
-                             decode_s=decode_s,
-                             wire_bytes=route.bytes_in + sent,
-                             evictions=req.evictions, ttlt_s=ttlt)
-        return True
+        with TraceAnnotation("frontdoor.deliver"):
+            finished, eng.finished = list(eng.finished), []
+            now = time.monotonic()
+            for req in finished:
+                route = self._routes.pop(req.uid, None)
+                if route is None:
+                    continue                  # not ours (direct submit)
+                self.admission.release(route.tenant)
+                tq = self.qos.tenant(route.tenant)
+                ttft = (req.t_first - req.t_submit
+                        if req.t_first is not None else None)
+                decode_s = (now - req.t_first
+                            if req.t_first is not None else 0.0)
+                ttlt = now - req.t_submit
+                header = {"rid": route.rid, "ttft_s": ttft, "ttlt_s": ttlt,
+                          "evictions": req.evictions,
+                          "accepted": req.accepted, "rejected": req.rejected,
+                          "rollbacks": req.rollbacks}
+                arr_header, payload = proto.pack_array(
+                    np.asarray(req.out, dtype=np.int32))
+                header.update(arr_header)
+                sent = 0
+                conn = route.sess.conn
+                delivered = False
+                if conn is not None and conn.open:
+                    try:
+                        sent = await conn.stream.send(MsgType.RESULT, header,
+                                                      payload)
+                        tq.bytes_out += sent
+                        delivered = True
+                    except (ConnectionError, RuntimeError, OSError):
+                        conn.open = False
+                if delivered:
+                    route.sess.mark_delivered(route.rid)
+                else:
+                    # park for a reattach — the session keeps the result
+                    # until the client resumes or the resume TTL sweeps it
+                    route.sess.parked.append((route.rid, header, payload))
+                tq.record_result(ttft_s=ttft, gen_tokens=len(req.out),
+                                 decode_s=decode_s,
+                                 wire_bytes=route.bytes_in + sent,
+                                 evictions=req.evictions, ttlt_s=ttlt)
+            return True
 
     # ------------------------------------------------------------------
     # session continuity
@@ -603,51 +608,59 @@ class FrontDoorServer:
 
     async def _submit(self, sess: _Session, conn: _Conn, header: dict,
                       payload: bytes, nbytes: int):
-        tq = self.qos.tenant(conn.tenant)
-        rid = header.get("rid")
-        if not isinstance(rid, int):
-            raise ProtocolError("SUBMIT carries no integer rid")
-        if rid in sess.rids or rid in sess.done_rids:
-            # idempotent re-SUBMIT after a reconnect: the request is
-            # already in flight (or parked), or its result was already
-            # delivered (the replay raced the parked-result flush) —
-            # re-ACK instead of doubling it
+        """One SUBMIT frame, from unpacking to the ACCEPTED / BUSY / ERROR
+        reply, inside a ``frontdoor.submit`` span that carries the engine
+        uid (``uid``) once the request has one."""
+        with TraceAnnotation("frontdoor.submit") as span:
+            tq = self.qos.tenant(conn.tenant)
+            rid = header.get("rid")
+            if not isinstance(rid, int):
+                raise ProtocolError("SUBMIT carries no integer rid")
+            if rid in sess.rids or rid in sess.done_rids:
+                # idempotent re-SUBMIT after a reconnect: the request is
+                # already in flight (or parked), or its result was already
+                # delivered (the replay raced the parked-result flush) —
+                # re-ACK instead of doubling it
+                tq.bytes_out += await conn.stream.send(MsgType.ACCEPTED,
+                                                       {"rid": rid})
+                return
+            tokens = proto.unpack_array(header, payload)
+            if tokens.ndim != 1 or tokens.dtype.name != "int32":
+                raise ProtocolError(f"SUBMIT payload must be a 1-D int32 "
+                                    f"token array, got {tokens.dtype.name}"
+                                    f"{tokens.shape}")
+            verdict = self.admission.try_admit(conn.tenant)
+            if verdict != ADMIT:
+                tq.busy_rejections += 1
+                retry = self.busy_retry_ms * (4 if verdict == BUSY_QUEUE
+                                              else 1)
+                tq.bytes_out += await conn.stream.send(
+                    MsgType.BUSY,
+                    {"rid": rid, "reason": verdict, "retry_after_ms": retry})
+                return
+            policy = self.admission.policy(conn.tenant)
+            req = Request(uid=next(self._uids),
+                          prompt=[int(t) for t in tokens],
+                          max_new_tokens=int(header.get("max_new", 16)),
+                          priority=int(header.get("priority",
+                                                  policy.priority)))
+            span.set_metadata(uid=req.uid)
+            try:
+                self.engine.submit(req)
+            except ValueError as e:
+                # engine-level refusal (empty/overlong prompt, footprint
+                # above the whole pool): an ERROR the client must not retry
+                # verbatim
+                self.admission.release(conn.tenant)
+                tq.errors += 1
+                tq.bytes_out += await conn.stream.send(
+                    MsgType.ERROR, {"rid": rid, "reason": str(e)})
+                return
+            self._routes[req.uid] = _Route(sess=sess, rid=rid,
+                                           tenant=conn.tenant, bytes_in=nbytes)
+            sess.rids[rid] = req.uid
             tq.bytes_out += await conn.stream.send(MsgType.ACCEPTED,
                                                    {"rid": rid})
-            return
-        tokens = proto.unpack_array(header, payload)
-        if tokens.ndim != 1 or tokens.dtype.name != "int32":
-            raise ProtocolError(f"SUBMIT payload must be a 1-D int32 token "
-                                f"array, got {tokens.dtype.name}"
-                                f"{tokens.shape}")
-        verdict = self.admission.try_admit(conn.tenant)
-        if verdict != ADMIT:
-            tq.busy_rejections += 1
-            retry = self.busy_retry_ms * (4 if verdict == BUSY_QUEUE else 1)
-            tq.bytes_out += await conn.stream.send(
-                MsgType.BUSY,
-                {"rid": rid, "reason": verdict, "retry_after_ms": retry})
-            return
-        policy = self.admission.policy(conn.tenant)
-        req = Request(uid=next(self._uids),
-                      prompt=[int(t) for t in tokens],
-                      max_new_tokens=int(header.get("max_new", 16)),
-                      priority=int(header.get("priority", policy.priority)))
-        try:
-            self.engine.submit(req)
-        except ValueError as e:
-            # engine-level refusal (empty/overlong prompt, footprint above
-            # the whole pool): an ERROR the client must not retry verbatim
-            self.admission.release(conn.tenant)
-            tq.errors += 1
-            tq.bytes_out += await conn.stream.send(
-                MsgType.ERROR, {"rid": rid, "reason": str(e)})
-            return
-        self._routes[req.uid] = _Route(sess=sess, rid=rid,
-                                       tenant=conn.tenant, bytes_in=nbytes)
-        sess.rids[rid] = req.uid
-        tq.bytes_out += await conn.stream.send(MsgType.ACCEPTED,
-                                               {"rid": rid})
 
     # ------------------------------------------------------------------
     # stats

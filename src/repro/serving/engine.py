@@ -101,6 +101,26 @@ prefill/decode iteration + one boundary — for callers that interleave
 engine work with other activity (the ``repro.frontdoor`` server's asyncio
 loop, open-loop arrival benchmarks).
 
+Measurement, always in place and cheap when nobody looks:
+
+* host spans in the profiler's trace (``jax.profiler.TraceAnnotation``,
+  constant names, nested on the host thread): ``engine.tick`` (one
+  ``tick()`` / one ``run()`` iteration), ``engine.boundary`` (a boundary
+  that reads state), ``engine.prefill_chunk``, ``engine.decode_window``,
+  and ``engine.device`` around every stretch that hands work to the
+  device or waits on it (program calls with the read of their result,
+  state reads and uploads);
+* counters in ``stats``: ``admitted`` and ``queue_wait_s`` (seconds from
+  ``submit``, or the re-queue after an eviction, to admission),
+  ``prefill_tokens`` and ``prefill_rows`` (prompt tokens and rows each
+  prefill chunk dispatched), and for a paged pool ``kv_written_page_s``,
+  ``kv_reserved_page_s`` and ``kv_pool_page_s`` (page-seconds between
+  boundary state reads, each interval weighted by the pages written,
+  reserved and in the pool at its start);
+* the C3-SL dispatch record (``record_dispatches()``, off by default):
+  which request sat in which slot at which positions in every codec call,
+  the grouping that decided each request's superposed cut features.
+
 The C3-SL codec applies to each step's cut-layer features across the
 active slots; on the chunked path the features are grouped PER POSITION
 (`sequence_group_encode` layout), the same group shape as the decode
@@ -123,6 +143,7 @@ from collections import Counter, deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import codecs as codecs_lib
 from repro.configs.base import ModelConfig
@@ -157,7 +178,11 @@ class Request:
     out: list = dataclasses.field(default_factory=list)
     done: bool = False
     t_submit: float = 0.0   # set by submit()
-    t_first: float | None = None  # first token observed (TTFT = t_first - t_submit)
+    # entered the queue: submit(), or the re-queue after an eviction
+    t_queued: float = 0.0
+    # first token read back by the host (TTFT = t_first - t_submit): the
+    # state read that first finds it, the moment it can be streamed
+    t_first: float | None = None
     evictions: int = 0      # times this request was preempted mid-flight
     # speculative-decoding per-request stats (0 unless the engine ran with
     # spec_decode): tokens emitted through verify rounds, draft positions
@@ -402,7 +427,22 @@ class BatchedEngine:
                       "wire_bytes_bwd": 0, "wire_bytes_draft": 0,
                       "eos_early_exits": 0, "evictions": 0, "withdrawn": 0,
                       "spec_windows": 0, "spec_rounds": 0, "spec_accepted": 0,
-                      "spec_rejected": 0, "spec_rollbacks": 0}
+                      "spec_rejected": 0, "spec_rollbacks": 0,
+                      "admitted": 0, "queue_wait_s": 0.0,
+                      "prefill_tokens": 0, "prefill_rows": 0,
+                      "kv_written_page_s": 0.0, "kv_reserved_page_s": 0.0,
+                      "kv_pool_page_s": 0.0}
+        # the engine's clock: submit/first-token stamps, queue wait and
+        # page-seconds (tests inject a fake one)
+        self.clock = time.monotonic
+        # (time, pages written, pages reserved) at the last boundary read
+        self._page_mark: tuple | None = None
+        # the C3-SL dispatch record (record_dispatches); None = off.  A
+        # decode window's steps are completed at the next state read
+        # (_pending_window), from the positions it returned.
+        self.dispatch_record: list | None = None
+        self._pending_window: tuple | None = None
+        self._rec_pos = None
         # effective-execution-mode surfacing (the silent-fallback fix):
         # kv_read_execution_mode says how the paged read ACTUALLY runs on
         # this host ("gather" | "pallas-compiled" | "pallas-interpret") and
@@ -854,7 +894,7 @@ class BatchedEngine:
                     f"request {req.uid}: needs {need} cache pages but the "
                     f"pool only has {self.paged.num_pages}; shorten the "
                     f"request or build the engine with more num_pages")
-        req.t_submit = time.monotonic()
+        req.t_submit = req.t_queued = self.clock()
         self.queue.append(req)
         self._dirty = True            # a later run() must re-check admission
 
@@ -877,15 +917,19 @@ class BatchedEngine:
             req = slot.req
             self.stats["withdrawn"] += 1
             if self.prefill_mode == "chunked":
-                st = {k: np.array(v)
-                      for k, v in jax.device_get(self.state).items()}
+                with TraceAnnotation("engine.device"):
+                    st = {k: np.array(v)
+                          for k, v in jax.device_get(self.state).items()}
+                if self.dispatch_record is not None:
+                    self._close_window_record(st)
                 n = int(st["out_len"][i])
                 req.out = [int(t) for t in st["out_buf"][i, :n]]
                 self._fold_spec_counters(i, req, st)
                 st["active"][i] = st["done"][i] = False
                 st["pos"][i] = st["last_tok"][i] = st["out_len"][i] = 0
                 st["out_buf"][i, :] = 0
-                self.state = jax.device_put(st)
+                with TraceAnnotation("engine.device"):
+                    self.state = jax.device_put(st)
             self._stream_mark.pop(uid, None)
             req.evictions += 1
             req.done = False
@@ -922,12 +966,13 @@ class BatchedEngine:
             return self._run_legacy(max_steps)
         steps = 0
         while steps < max_steps:
-            self._boundary()
-            if not (self.queue or self.active):
-                break
-            steps += self._tick_body(max_steps - steps)
-            if self._sanitizer is not None:
-                self._sanitizer.on_tick(self)
+            with TraceAnnotation("engine.tick"):
+                self._boundary()
+                if not (self.queue or self.active):
+                    break
+                steps += self._tick_body(max_steps - steps)
+                if self._sanitizer is not None:
+                    self._sanitizer.on_tick(self)
         self._boundary()
         return self.finished
 
@@ -940,18 +985,20 @@ class BatchedEngine:
         ``self.finished`` before control returns.  Returns False when the
         engine is idle (no queued or resident work) — the caller's cue to
         sleep instead of spinning."""
-        if self.prefill_mode == "decode":
-            return bool(self.step())
-        self._boundary()
-        if not (self.queue or self.active):
-            return False
-        self._tick_body(self.sync_every)
-        if self._sanitizer is not None:
-            # before the trailing boundary: done-but-unretired slots are
-            # still resident, so the dead/live cut probe sees the mix
-            self._sanitizer.on_tick(self)
-        self._boundary()
-        return True
+        with TraceAnnotation("engine.tick"):
+            if self.prefill_mode == "decode":
+                return bool(self.step())
+            self._boundary()
+            if not (self.queue or self.active):
+                return False
+            self._tick_body(self.sync_every)
+            if self._sanitizer is not None:
+                # before the trailing boundary: done-but-unretired slots
+                # are still resident, so the dead/live cut probe sees the
+                # mix
+                self._sanitizer.on_tick(self)
+            self._boundary()
+            return True
 
     def _tick_body(self, budget: int) -> int:
         """One scheduler iteration (between boundaries): prefill according
@@ -993,11 +1040,12 @@ class BatchedEngine:
         n_rounds = -(-min(n, self._window_len) // k)
         bucket = self._bucket()
         dkey = codecs_lib.program_key(self.draft_codec)
-        i, acc, rej, rol, self.cache, self.state = \
-            self._spec_programs[(bucket, dkey, k)](
-                self.params, self.cache, self.state, jnp.int32(n_rounds))
-        rounds, acc, rej, rol = (int(v) for v in
-                                 jax.device_get((i, acc, rej, rol)))
+        with TraceAnnotation("engine.device"):
+            i, acc, rej, rol, self.cache, self.state = \
+                self._spec_programs[(bucket, dkey, k)](
+                    self.params, self.cache, self.state, jnp.int32(n_rounds))
+            rounds, acc, rej, rol = (int(v) for v in
+                                     jax.device_get((i, acc, rej, rol)))
         self.stats["dispatches"] += 1
         self.stats["decode_steps"] += acc
         self.stats["spec_windows"] += 1
@@ -1027,43 +1075,52 @@ class BatchedEngine:
         verify/commit loop instead (bit-identical greedy outputs)."""
         if n <= 0:
             return 0
-        k = self._spec_k()
-        if k > 1:
-            return self._spec_window(n, k)
-        n = min(n, self._window_len)
-        keys = jax.random.split(self.rng, self._window_len + 1)
-        self.rng = keys[0]
-        bucket = self._bucket()
-        stop_on_done = self._pool_starved()
-        i, self.cache, self.state = self._programs[bucket]["window"](
-            self.params, self.cache, self.state, keys[1:], jnp.int32(n),
-            jnp.bool_(stop_on_done))
-        self.stats["dispatches"] += 1
-        executed = int(i)
-        self.stats["decode_steps"] += executed
-        self._account_fwd_bytes(executed * self._step_wire_bytes())
-        if stop_on_done and executed < n:
-            # a slot finished while the page pool was starving the head of
-            # the queue.  Retire it from THIS host sync: its outputs are
-            # captured at their actual emitted length and its whole
-            # PageAllocator reservation is freed right here, instead of the
-            # worst-case prompt+max_new pages staying held until the next
-            # retire sweep.  The extra device round-trip only happens on
-            # the already-rare starved-pool early exit.
-            st = {k: np.array(v)
-                  for k, v in jax.device_get(self.state).items()}
-            if bool(np.any(st["active"] & ~st["done"])):
-                # the early exit actually cut short a window that still had
-                # live slots (vs the batch simply draining)
-                self.stats["eos_early_exits"] += 1
-            self._collect_stream(st)
-            if self._retire_done(st):
-                self.state = jax.device_put(st)
-        if bucket is not None:
-            self.r_served[bucket] += executed
-        if executed:
-            self._dirty = True
-        return executed
+        with TraceAnnotation("engine.decode_window"):
+            k = self._spec_k()
+            if k > 1:
+                return self._spec_window(n, k)
+            n = min(n, self._window_len)
+            bucket = self._bucket()
+            stop_on_done = self._pool_starved()
+            if self.dispatch_record is not None:
+                self._open_window_record()
+            with TraceAnnotation("engine.device"):
+                keys = jax.random.split(self.rng, self._window_len + 1)
+                self.rng = keys[0]
+                i, self.cache, self.state = self._programs[bucket]["window"](
+                    self.params, self.cache, self.state, keys[1:],
+                    jnp.int32(n), jnp.bool_(stop_on_done))
+                executed = int(i)
+            self.stats["dispatches"] += 1
+            self.stats["decode_steps"] += executed
+            self._account_fwd_bytes(executed * self._step_wire_bytes())
+            if stop_on_done and executed < n:
+                # a slot finished while the page pool was starving the
+                # head of the queue.  Retire it from THIS host sync: its
+                # outputs are captured at their actual emitted length and
+                # its whole PageAllocator reservation is freed right here,
+                # instead of the worst-case prompt+max_new pages staying
+                # held until the next retire sweep.  The extra device
+                # round-trip only happens on the already-rare starved-pool
+                # early exit.
+                with TraceAnnotation("engine.device"):
+                    st = {k: np.array(v)
+                          for k, v in jax.device_get(self.state).items()}
+                if self.dispatch_record is not None:
+                    self._close_window_record(st)
+                if bool(np.any(st["active"] & ~st["done"])):
+                    # the early exit actually cut short a window that still
+                    # had live slots (vs the batch simply draining)
+                    self.stats["eos_early_exits"] += 1
+                self._collect_stream(st)
+                if self._retire_done(st):
+                    with TraceAnnotation("engine.device"):
+                        self.state = jax.device_put(st)
+            if bucket is not None:
+                self.r_served[bucket] += executed
+            if executed:
+                self._dirty = True
+            return executed
 
     def _pool_starved(self) -> bool:
         """True when the head-of-queue request is blocked on pages — the
@@ -1092,44 +1149,44 @@ class BatchedEngine:
         """One chunk of up to chunk_size prompt tokens for EVERY slot still
         prefilling, in a single dispatch (ragged tails padded under the
         length mask; rows not prefilling are fully masked)."""
-        B, C = self.num_slots, self.chunk_size
-        tokens = np.zeros((B, C), np.int32)
-        valid = np.zeros((B, C), bool)
-        completes = np.zeros((B,), bool)
-        any_rows = False
-        for i, slot in enumerate(self.slots):
-            if slot.req is None or slot.ingested >= len(slot.feed):
-                continue
-            seg = slot.feed[slot.ingested:slot.ingested + C]
-            tokens[i, :len(seg)] = seg
-            valid[i, :len(seg)] = True
-            slot.ingested += len(seg)
-            completes[i] = slot.ingested >= len(slot.feed)
-            any_rows = True
-        if not any_rows:
-            return
-        self.rng, key = jax.random.split(self.rng)
-        bucket = self._bucket()
-        self.cache, self.state = self._programs[bucket]["prefill"](
-            self.params, self.cache, self.state, jnp.asarray(tokens),
-            jnp.asarray(valid), jnp.asarray(completes), key)
-        self.stats["dispatches"] += 1
-        self.stats["prefill_chunks"] += 1
-        self._account_fwd_bytes(self._chunk_wire_bytes())
-        if bucket is not None:
-            self.r_served[bucket] += 1
-        if completes.any():
-            # the completing dispatch commits the row's first token: stamp
-            # TTFT here, so the metric has per-chunk resolution at EVERY
-            # interleave setting.  Dispatch is async — block until the
-            # token actually exists, or enqueue time would flatter
-            # schedules that batch many dispatches between host syncs.
-            jax.block_until_ready(self.state["out_len"])
-            now = time.monotonic()
-            for i in np.flatnonzero(completes):
-                if self.slots[i].req.t_first is None:
-                    self.slots[i].req.t_first = now
-            self._dirty = True
+        with TraceAnnotation("engine.prefill_chunk"):
+            B, C = self.num_slots, self.chunk_size
+            tokens = np.zeros((B, C), np.int32)
+            valid = np.zeros((B, C), bool)
+            completes = np.zeros((B,), bool)
+            fed = []                       # (slot, tokens fed) per row
+            for i, slot in enumerate(self.slots):
+                if slot.req is None or slot.ingested >= len(slot.feed):
+                    continue
+                seg = slot.feed[slot.ingested:slot.ingested + C]
+                tokens[i, :len(seg)] = seg
+                valid[i, :len(seg)] = True
+                slot.ingested += len(seg)
+                completes[i] = slot.ingested >= len(slot.feed)
+                fed.append((i, len(seg)))
+            if not fed:
+                return
+            if self.dispatch_record is not None:
+                self.dispatch_record.append((self.clock(), "P", [
+                    (i, self.slots[i].req.uid, self.slots[i].ingested - n, n)
+                    for i, n in fed]))
+            bucket = self._bucket()
+            with TraceAnnotation("engine.device"):
+                self.rng, key = jax.random.split(self.rng)
+                self.cache, self.state = self._programs[bucket]["prefill"](
+                    self.params, self.cache, self.state, jnp.asarray(tokens),
+                    jnp.asarray(valid), jnp.asarray(completes), key)
+            self.stats["dispatches"] += 1
+            self.stats["prefill_chunks"] += 1
+            self.stats["prefill_tokens"] += sum(n for _, n in fed)
+            self.stats["prefill_rows"] += B * C
+            self._account_fwd_bytes(self._chunk_wire_bytes())
+            if bucket is not None:
+                self.r_served[bucket] += 1
+            if completes.any():
+                # the completing dispatch commits the row's first token; the
+                # next boundary reads it back (and stamps t_first there)
+                self._dirty = True
 
     def _retire_done(self, st, now: float | None = None) -> bool:
         """Retire every slot whose done flag is set in the host state copy
@@ -1138,7 +1195,7 @@ class BatchedEngine:
         so a starved pool gets the pages at the earliest host-visible
         instant — from the decode window's EOS early exit."""
         if now is None:
-            now = time.monotonic()
+            now = self.clock()
         touched = False
         for i, slot in enumerate(self.slots):
             if slot.req is None:
@@ -1212,6 +1269,7 @@ class BatchedEngine:
         n = int(st["out_len"][i])
         req.out = [int(t) for t in st["out_buf"][i, :n]]
         req.evictions += 1
+        req.t_queued = self.clock()
         self.stats["evictions"] += 1
         self._fold_spec_counters(i, req, st)
         slot.req = None
@@ -1268,48 +1326,149 @@ class BatchedEngine:
         must not pay a blocking device_get per chunk."""
         if not self._dirty:
             return
-        self._dirty = False
-        st = {k: np.array(v) for k, v in jax.device_get(self.state).items()}
-        self._collect_stream(st)
-        touched = self._retire_done(st)
-        admitted: list[int] = []
-        while self.queue:
-            head = self.queue[0]
-            i = next((j for j, s in enumerate(self.slots) if s.req is None),
-                     None)
-            if i is None or not self._alloc_slot_pages(i, head):
-                if not self._preempt_for(st, head):
-                    break                  # FIFO: wait for pages to free
+        with TraceAnnotation("engine.boundary"):
+            self._dirty = False
+            with TraceAnnotation("engine.device"):
+                st = {k: np.array(v)
+                      for k, v in jax.device_get(self.state).items()}
+            now = self.clock()
+            if self.dispatch_record is not None:
+                self._close_window_record(st)
+            self._collect_stream(st)
+            touched = self._retire_done(st, now)
+            admitted: list[int] = []
+            while self.queue:
+                head = self.queue[0]
+                i = next((j for j, s in enumerate(self.slots)
+                          if s.req is None), None)
+                if i is None or not self._alloc_slot_pages(i, head):
+                    if not self._preempt_for(st, head):
+                        break              # FIFO: wait for pages to free
+                    touched = True
+                    continue               # room was made — retry the head
+                slot = self.slots[i]
+                slot.req = self.queue.popleft()
+                self._count_admission(slot.req, now)
+                slot.ingested = 0
+                # re-admitted (evicted) requests re-prefill their emitted
+                # tokens too, and resume with out_len/out_buf pre-seeded so
+                # the prefill-completing dispatch commits token k+1
+                slot.feed = list(slot.req.prompt) + list(slot.req.out)
+                k = len(slot.req.out)
+                st["active"][i] = st["done"][i] = False
+                st["pos"][i] = st["last_tok"][i] = 0
+                st["out_len"][i] = k
+                st["max_new"][i] = slot.req.max_new_tokens
+                st["out_buf"][i, :] = 0
+                if k:
+                    st["out_buf"][i, :k] = slot.req.out
+                # stream watermark: tokens in req.out were already
+                # delivered (or re-prefilled after eviction) — only NEW
+                # emissions stream
+                self._stream_mark.setdefault(slot.req.uid, k)
+                admitted.append(i)
                 touched = True
-                continue                   # room was made — retry the head
-            slot = self.slots[i]
-            slot.req = self.queue.popleft()
-            slot.ingested = 0
-            # re-admitted (evicted) requests re-prefill their emitted
-            # tokens too, and resume with out_len/out_buf pre-seeded so
-            # the prefill-completing dispatch commits token k+1
-            slot.feed = list(slot.req.prompt) + list(slot.req.out)
-            k = len(slot.req.out)
-            st["active"][i] = st["done"][i] = False
-            st["pos"][i] = st["last_tok"][i] = 0
-            st["out_len"][i] = k
-            st["max_new"][i] = slot.req.max_new_tokens
-            st["out_buf"][i, :] = 0
-            if k:
-                st["out_buf"][i, :k] = slot.req.out
-            # stream watermark: tokens in req.out were already delivered
-            # (or re-prefilled after eviction) — only NEW emissions stream
-            self._stream_mark.setdefault(slot.req.uid, k)
-            admitted.append(i)
-            touched = True
-        if touched:
-            self.state = jax.device_put(st)
-        if admitted:
-            if self.paged is not None:
-                self.cache = {**self.cache, "pages": jnp.asarray(self._table)}
-            mask = np.zeros((self.num_slots,), bool)
-            mask[admitted] = True
-            self.cache = self._reset(self.cache, jnp.asarray(mask))
+            if self.paged is not None and self._linear_backed:
+                self._integrate_pages(st, now)
+            if touched or admitted:
+                with TraceAnnotation("engine.device"):
+                    if touched:
+                        self.state = jax.device_put(st)
+                    if admitted:
+                        if self.paged is not None:
+                            self.cache = {**self.cache,
+                                          "pages": jnp.asarray(self._table)}
+                        mask = np.zeros((self.num_slots,), bool)
+                        mask[admitted] = True
+                        self.cache = self._reset(self.cache,
+                                                 jnp.asarray(mask))
+
+    def _count_admission(self, req: Request, now: float):
+        self.stats["admitted"] += 1
+        self.stats["queue_wait_s"] += now - req.t_queued
+
+    def _integrate_pages(self, st, now: float):
+        """Add the page-seconds since the previous boundary read, weighted
+        by the pages at that read, and note the pages at this one (``st``
+        as the boundary leaves it): pages holding a written position,
+        pages reserved by resident slots, and the whole pool.  Admission
+        waits on the reserved pages.  The written ones read low by the
+        prompts prefilled in an interval: a slot admitted at its start
+        counts there with none."""
+        if self._page_mark is not None:
+            t, written, reserved = self._page_mark
+            dt = now - t
+            self.stats["kv_written_page_s"] += dt * written
+            self.stats["kv_reserved_page_s"] += dt * reserved
+            self.stats["kv_pool_page_s"] += dt * self.paged.num_pages
+        ps = self.paged.page_size
+        written = reserved = 0
+        for i, s in enumerate(self.slots):
+            if s.req is not None:
+                written += -(-int(st["pos"][i]) // ps)
+                reserved += len(s.pages)
+        self._page_mark = (now, written, reserved)
+
+    # ------------------------------------------------------------------
+    # the C3-SL dispatch record
+    # ------------------------------------------------------------------
+
+    def record_dispatches(self, on: bool = True) -> list | None:
+        """Turn the dispatch record on (returns the fresh list it fills)
+        or off (returns None).
+
+        One entry per codec call of the chunked path, in dispatch order:
+        a prefill chunk is ``(t, "P", [(slot, uid, start, n), ...])`` —
+        the ``n`` prompt positions from ``start`` that request ``uid`` fed
+        through ``slot`` — and each decode step of a window is ``(t, "D",
+        [(slot, uid, pos), ...])`` for the slots live in it; ``t`` is the
+        dispatch time on :attr:`clock`.  Slots ``R*g .. R*g+R-1`` form
+        C3-SL group ``g``, so an entry says which requests were
+        superposed together.  Prefill rows come from the host-side chunk
+        the engine packs; a window's steps are completed at the next
+        state read the engine makes anyway (a boundary, an early exit or
+        a withdraw), from the positions it returns, so recording adds no
+        host sync past the one read of positions made here.  Speculative
+        windows and the legacy ``prefill_mode="decode"`` path are not
+        recorded.  The list grows by one entry per step: an operator
+        turns it on for an audit, not for the life of a server."""
+        self._pending_window = None
+        if not on:
+            self.dispatch_record = None
+            return None
+        with TraceAnnotation("engine.device"):
+            self._rec_pos = np.array(jax.device_get(self.state["pos"]))
+        self.dispatch_record = []
+        return self.dispatch_record
+
+    def _open_window_record(self):
+        """Note which requests the window about to be dispatched decodes,
+        and from which position (the position the last state read found,
+        or the end of the feed for a prefill completed since)."""
+        starts = [(i, s.req.uid, max(int(self._rec_pos[i]), len(s.feed)))
+                  for i, s in enumerate(self.slots)
+                  if s.req is not None and s.ingested >= len(s.feed)]
+        self._pending_window = (len(self.dispatch_record), self.clock(),
+                                starts)
+
+    def _close_window_record(self, st):
+        """Complete the pending window's steps from the host state copy
+        ``st`` (before the caller retires or admits anything): each slot
+        decoded from its noted start up to its returned position.  The
+        caller's later edits of ``st["pos"]`` (retire, admit) are what the
+        next window's starts read, so it is kept by reference."""
+        pend, self._pending_window = self._pending_window, None
+        self._rec_pos = st["pos"]
+        if pend is None:
+            return
+        idx, t, starts = pend
+        steps: list[list] = []
+        for i, uid, start in starts:
+            for k in range(int(st["pos"][i]) - start):
+                if k == len(steps):
+                    steps.append([])
+                steps[k].append((i, uid, start + k))
+        self.dispatch_record[idx:idx] = [(t, "D", rows) for rows in steps]
 
     # ------------------------------------------------------------------
     # page bookkeeping (host side; no-ops for the contiguous layout)
@@ -1351,6 +1510,7 @@ class BatchedEngine:
                 if not self._alloc_slot_pages(i, self.queue[0]):
                     break
                 slot.req = self.queue.popleft()
+                self._count_admission(slot.req, self.clock())
                 slot.pos = 0
                 slot.in_prompt = 0
                 slot.feed = list(slot.req.prompt) + list(slot.req.out)
@@ -1383,9 +1543,11 @@ class BatchedEngine:
         # paged: empty rows hold no pages, so their writes MUST be masked
         live = jnp.asarray(occupied) if self.paged is not None else None
         bucket = self._bucket()
-        nxt, self.cache = self._programs[bucket]["legacy"](
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(pos), key, live)
+        with TraceAnnotation("engine.device"):
+            nxt, self.cache = self._programs[bucket]["legacy"](
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(pos), key, live)
+            nxt = np.asarray(nxt)
         self.stats["dispatches"] += 1
         # one fused batch step per dispatch — same unit as the chunked
         # path's decode_steps (NOT per-slot generated tokens)
@@ -1393,7 +1555,6 @@ class BatchedEngine:
         self._account_fwd_bytes(self._step_wire_bytes())
         if bucket is not None:
             self.r_served[bucket] += 1
-        nxt = np.asarray(nxt)
         for i, s in enumerate(self.slots):
             if s.req is None:
                 continue
@@ -1407,7 +1568,7 @@ class BatchedEngine:
                 tok = int(nxt[i])
                 s.req.out.append(tok)
                 if s.req.t_first is None:
-                    s.req.t_first = time.monotonic()
+                    s.req.t_first = self.clock()
                 self._tokens_decoded += 1
                 if (self.eos_id is not None and tok == self.eos_id) \
                         or len(s.req.out) >= s.req.max_new_tokens \
@@ -1422,6 +1583,7 @@ class BatchedEngine:
     def _run_legacy(self, max_steps: int) -> list[Request]:
         steps = 0
         while (self.queue or self.active) and steps < max_steps:
-            self.step()
+            with TraceAnnotation("engine.tick"):
+                self.step()
             steps += 1
         return self.finished
