@@ -25,6 +25,13 @@ or int8-quantized KV pools.  MLA latent caches and prefill stay on the
 gather path — the serving engine falls back LOUDLY (see
 ``BatchedEngine(kv_read=...)``), never silently.
 
+The pools may be one layer's ``(num_pages, ps, KV, hd)`` or the
+layer-flattened stack ``(L * num_pages, ps, KV, hd)`` that the layer scan
+carries (``repro.models.stack``), with the table offset by
+``l * num_pages``: the block index maps take the page id from the table
+either way, so the kernel, its operands and their order are the same, and
+no layer's pool is sliced out for it.
+
 Like the circconv kernels, this runs in interpret mode off-TPU
 (``circconv._interpret``); callers surface the effective execution mode
 instead of pretending interpret numbers are kernel numbers.
@@ -138,7 +145,8 @@ def _compiler_params(blocks, scratch, temps):
 
 def paged_attention(q, k_pool, v_pool, table, pos, *, length: int,
                     sliding_window=None):
-    """q (B, 1, H, hd) post-rope; k/v pools (num_pages, ps, KV, hd); table
+    """q (B, 1, H, hd) post-rope; k/v pools (num_pages, ps, KV, hd), or
+    the layer-flattened stack with ``table`` offset to one layer; table
     (B, P) int32; pos (B,) int32.  Returns the (B, 1, H*hd) attention
     output — ``attention.sdpa_decode`` over ``gather_pages`` of the same
     pools, computed in-kernel."""

@@ -277,11 +277,12 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig, *,
             params["stack"], cache["stack"], cfg, h, pos, kv_read=kv_read,
             **kw)
     else:
-        n_cut = cfg.num_superblocks // 2
-        p_front, p_back = _split_stacked(params["stack"], n_cut)
-        c_front, c_back = _split_stacked(cache["stack"], n_cut)
-        h, nc_front = stack_lib.apply_stack_decode(p_front, c_front, cfg, h, pos,
-                                                   kv_read=kv_read, **kw)
+        # both sides of the cut run on the same stacked weights and cache,
+        # each updating its own layers in place: neither is split at the cut
+        n_cut, n_sb = cfg.num_superblocks // 2, cfg.num_superblocks
+        h, c_stack = stack_lib.apply_stack_decode(
+            params["stack"], cache["stack"], cfg, h, pos, kv_read=kv_read,
+            layers=range(n_cut), **kw)
         B, _, d = h.shape
         if live is not None:
             # A non-live row's cut-layer feature is attention over whatever
@@ -293,10 +294,9 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig, *,
         cut = h.reshape(B, d)
         payload = codec.encode(codec_params, cut)
         h = codec.decode(codec_params, payload).reshape(B, 1, d)
-        h, nc_back = stack_lib.apply_stack_decode(p_back, c_back, cfg, h, pos,
-                                                  kv_read=kv_read, **kw)
-        new_cache["stack"] = jax.tree.map(
-            lambda f, b: jnp.concatenate([f, b], axis=0), nc_front, nc_back)
+        h, new_cache["stack"] = stack_lib.apply_stack_decode(
+            params["stack"], c_stack, cfg, h, pos, kv_read=kv_read,
+            layers=range(n_cut, n_sb), **kw)
 
     h = _apply_norm(cfg, params["final_norm"], h)
     if return_cut:
@@ -349,11 +349,10 @@ def chunk_forward(params, cache, tokens, pos, cfg: ModelConfig, *,
     else:
         from repro.codecs.c3sl import (sequence_group_decode,
                                        sequence_group_encode)
-        n_cut = cfg.num_superblocks // 2
-        p_front, p_back = _split_stacked(params["stack"], n_cut)
-        c_front, c_back = _split_stacked(cache["stack"], n_cut)
-        h, nc_front = stack_lib.apply_stack_prefill(p_front, c_front, cfg, h,
-                                                    pos, valid, **kw)
+        n_cut, n_sb = cfg.num_superblocks // 2, cfg.num_superblocks
+        h, c_stack = stack_lib.apply_stack_prefill(
+            params["stack"], cache["stack"], cfg, h, pos, valid,
+            layers=range(n_cut), **kw)
         # same containment as decode_step: positions that are not real
         # prompt tokens (idle slots, ragged chunk tails) carry garbage
         # features that would otherwise superpose onto live rows — and vary
@@ -363,10 +362,9 @@ def chunk_forward(params, cache, tokens, pos, cfg: ModelConfig, *,
         payload = sequence_group_encode(codec, codec_params, h.swapaxes(0, 1))
         h = sequence_group_decode(codec, codec_params, payload,
                                   C, B).swapaxes(0, 1)
-        h, nc_back = stack_lib.apply_stack_prefill(p_back, c_back, cfg, h,
-                                                   pos, valid, **kw)
-        new_cache["stack"] = jax.tree.map(
-            lambda f, b: jnp.concatenate([f, b], axis=0), nc_front, nc_back)
+        h, new_cache["stack"] = stack_lib.apply_stack_prefill(
+            params["stack"], c_stack, cfg, h, pos, valid,
+            layers=range(n_cut, n_sb), **kw)
     return h, new_cache, cut_seq
 
 
@@ -423,6 +421,12 @@ def verify_chunk(params, cache, tokens, pos, cfg: ModelConfig, *,
     at position j are exact (equal to vanilla decode's) whenever every
     earlier position's input matched vanilla's — the acceptance rule only
     consumes logits inside that prefix.
+
+    Cost: the forward writes the verify chunk into the stacked cache in
+    place (see ``stack._scan_layers``) and this function drops the result,
+    so XLA must keep the old pools alive beside the written ones — a copy
+    of every pool per verify round that the in-place update avoids
+    everywhere else.
     """
     h, _, cut_seq = chunk_forward(params, cache, tokens, pos, cfg,
                                   codec=codec, codec_params=codec_params,

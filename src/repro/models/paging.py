@@ -29,6 +29,12 @@ separate page tables because a slot needs a different page count in
 each; ring slots keep the contiguous path's ``pos % len_swa`` addressing
 on top of the table.
 
+A pool here may be one layer's ``(num_pages, ps, ...)`` or the
+layer-flattened stack ``(L * num_pages, ps, ...)`` that the layer scan
+carries (``repro.models.stack``): layer ``l``'s pages are then addressed
+by the same table offset by ``l * num_pages``, and every function below
+reads or writes exactly the pages the table names, in place.
+
 Page *allocation* is host-side policy and lives with the serving engine
 (``repro.serving.paging``); this module is only the device-side layout.
 """
@@ -79,6 +85,9 @@ class PagedLayout:
 def gather_pages(pool, table, length: int):
     """Slot-major view of a paged pool: (num_pages, ps, ...) -> (B, length, ...).
 
+    ``pool`` may be the layer-flattened stack (L*num_pages, ps, ...) with
+    ``table`` offset by ``l*num_pages`` to view layer ``l``.
+
     ``view[b, t] == pool[table[b, t // ps], t % ps]`` — exactly the
     contiguous cache layout for slot b, so downstream attention math is
     unchanged.  Logical pages past a slot's allocation read whatever page
@@ -112,7 +121,8 @@ def scatter_rows(pool, table, slots, vals, *, live=None):
 
     Rows where ``live`` is False write nothing (offset pushed past the
     page -> ``mode="drop"``), so done/empty/mid-prefill rows never touch
-    pages they do not own.
+    pages they do not own.  On the layer-flattened stack (``table`` offset
+    by ``l*num_pages``) the write lands in layer ``l``'s pages, in place.
     """
     B, P = table.shape
     ps = pool.shape[1]
@@ -131,7 +141,8 @@ def scatter_chunk(pool, table, slots, valid, vals):
 
     ``valid`` (B, C) marks real tokens; padded tails are dropped.  Chunk
     positions are distinct within a row and rows own disjoint pages, so
-    the scatter has no write collisions.
+    the scatter has no write collisions.  Like :func:`scatter_rows`, it
+    takes the layer-flattened stack with an offset table too.
     """
     B, P = table.shape
     ps = pool.shape[1]

@@ -9,6 +9,14 @@ independent of depth — essential for the 88-layer dry-runs.
 
 Sublayer kinds: attn, mla, mlp, moe, mamba, rwkv_tm, rwkv_cm, cross.
 Every sublayer is pre-norm residual: h = h + f(norm(h)).
+
+Serving (decode and chunked prefill) scans the layer index instead: the
+stacked cache leaves ride the scan carry and each layer updates its own
+part of them in place — a paged pool ``(L, N, ps, ...)`` as the
+layer-flattened pool ``(L*N, ps, ...)`` behind page tables offset by
+``l*N``, any other leaf at index ``l`` (see ``_scan_layers``).  A range of
+layers (one side of the C3-SL cut) runs on the same stacked weights and
+cache, so nothing is split at the cut or concatenated after it.
 """
 from __future__ import annotations
 
@@ -343,25 +351,94 @@ def apply_superblock_decode(p_sb, cache_sb, cfg: ModelConfig, h, pos, *,
     return h, new_cache
 
 
+def is_pool(kind: str, paged) -> bool:
+    """Whether a sublayer's cache leaves are page pools shared by every slot
+    (attn/mla on the paged layout, as :func:`init_sublayer_cache` builds
+    them) rather than per-slot leaves."""
+    return paged is not None and kind in ("attn", "mla")
+
+
+def _layer_view(leaves, l, pooled: bool):
+    """One sublayer's cache at layer ``l`` of the stacked cache.  A pool
+    ``(L, N, ps, ...)`` is read as the layer-flattened pool
+    ``(L*N, ps, ...)``, a free reshape of a row-major array that the page
+    tables address once offset by ``l*N``; any other leaf ``(L, B, ...)``
+    is indexed at ``l``."""
+    if pooled:
+        return {n: a.reshape(-1, *a.shape[2:]) for n, a in leaves.items()}
+    return {n: jax.lax.dynamic_index_in_dim(a, l, keepdims=False)
+            for n, a in leaves.items()}
+
+
+def _put_layer(leaves, new, l, pooled: bool):
+    """Store what :func:`_layer_view` handed out back in the stack: a pool's
+    writes landed in place, so it only takes its stacked shape again; any
+    other leaf is written at layer ``l``."""
+    if pooled:
+        return {n: new[n].reshape(a.shape) for n, a in leaves.items()}
+    return {n: jax.lax.dynamic_update_index_in_dim(a, new[n], l, 0)
+            for n, a in leaves.items()}
+
+
+def _scan_layers(stacked, cache, cfg: ModelConfig, h, layers, apply_sb,
+                 paged, pages, pages_swa):
+    """Run ``apply_sb(p_sb, cache_sb, h, pages, pages_swa)`` for each layer
+    ``l`` in ``layers`` (a range; all of them if None) of the stacked
+    params and cache.
+
+    Only the layer index is scanned.  The weights are indexed at ``l``
+    inside the body, and the whole stacked cache rides the carry, each
+    layer reading and writing its own part of it in place: pools
+    (:func:`is_pool`) through page tables offset by ``l * num_pages`` into
+    the layer-flattened pools, other leaves at index ``l``.  No layer's pool is sliced out of the stack, no
+    stack is assembled from the layers' outputs, and a range of layers
+    (one side of the C3-SL cut) slices neither weights nor cache, so XLA
+    keeps one buffer per leaf across the loop and across the cut."""
+    kinds = {f"l{li}_{si}_{kind}": kind
+             for li, layer in enumerate(cfg.block_pattern)
+             for si, kind in enumerate(layer)}
+    pooled = {key: is_pool(kind, paged) for key, kind in kinds.items()}
+    if layers is None:
+        layers = range(jax.tree.leaves(stacked)[0].shape[0])
+
+    def tables(l):
+        if paged is None:
+            return pages, pages_swa
+        swa = None if pages_swa is None else pages_swa + l * paged.num_pages_swa
+        return pages + l * paged.num_pages, swa
+
+    def body(carry, l):
+        h, cache = carry
+        p_sb = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, l, keepdims=False),
+            stacked)
+        view = {key: _layer_view(cache[key], l, pooled[key]) for key in kinds}
+        h, new = apply_sb(p_sb, view, h, *tables(l))
+        cache = {key: _put_layer(cache[key], new[key], l, pooled[key])
+                 for key in kinds}
+        return (h, cache), None
+
+    layer_ids = jnp.arange(layers.start, layers.stop, dtype=jnp.int32)
+    (h, cache), _ = jax.lax.scan(body, (h, cache), layer_ids)
+    return h, cache
+
+
 def apply_stack_decode(stacked, cache, cfg: ModelConfig, h, pos, *, memory=None,
                        paged=None, pages=None, pages_swa=None, live=None,
-                       kv_read="gather"):
-    """One-token decode through the whole stack; cache leaves have leading
-    superblock dim.  Returns (h, new_cache).  Page tables (``pages`` /
-    ``pages_swa``) are shared by every superblock — the scan closes over
-    them; only the pools are scanned."""
+                       kv_read="gather", layers=None):
+    """One-token decode through superblocks ``layers`` (a range; all if
+    None) of the stacked params and cache.  Returns (h, new_cache), the
+    cache updated in place (see :func:`_scan_layers`).  Page tables
+    (``pages`` / ``pages_swa``) are shared by every superblock."""
 
-    def body(h, xs):
-        p_sb, cache_sb = xs
-        h, new_cache_sb = apply_superblock_decode(p_sb, cache_sb, cfg, h, pos,
-                                                  memory=memory, paged=paged,
-                                                  pages=pages,
-                                                  pages_swa=pages_swa,
-                                                  live=live, kv_read=kv_read)
-        return h, new_cache_sb
+    def apply_sb(p_sb, cache_sb, h, pages_l, pages_swa_l):
+        return apply_superblock_decode(p_sb, cache_sb, cfg, h, pos,
+                                       memory=memory, paged=paged,
+                                       pages=pages_l, pages_swa=pages_swa_l,
+                                       live=live, kv_read=kv_read)
 
-    h, new_cache = jax.lax.scan(body, h, (stacked, cache))
-    return h, new_cache
+    return _scan_layers(stacked, cache, cfg, h, layers, apply_sb, paged,
+                        pages, pages_swa)
 
 
 # ---------------------------------------------------------------------------
@@ -458,17 +535,16 @@ def apply_superblock_prefill(p_sb, cache_sb, cfg: ModelConfig, h, pos, valid, *,
 
 
 def apply_stack_prefill(stacked, cache, cfg: ModelConfig, h, pos, valid, *,
-                        memory=None, paged=None, pages=None, pages_swa=None):
-    """Chunked prefill through the whole stack; cache leaves have leading
-    superblock dim.  Returns (h (B,C,d), new_cache)."""
+                        memory=None, paged=None, pages=None, pages_swa=None,
+                        layers=None):
+    """Chunked prefill through superblocks ``layers`` (a range; all if None)
+    of the stacked params and cache.  Returns (h (B,C,d), new_cache), the
+    cache updated in place as in :func:`apply_stack_decode`."""
 
-    def body(h, xs):
-        p_sb, cache_sb = xs
-        h, new_cache_sb = apply_superblock_prefill(p_sb, cache_sb, cfg, h, pos,
-                                                   valid, memory=memory,
-                                                   paged=paged, pages=pages,
-                                                   pages_swa=pages_swa)
-        return h, new_cache_sb
+    def apply_sb(p_sb, cache_sb, h, pages_l, pages_swa_l):
+        return apply_superblock_prefill(p_sb, cache_sb, cfg, h, pos, valid,
+                                        memory=memory, paged=paged,
+                                        pages=pages_l, pages_swa=pages_swa_l)
 
-    h, new_cache = jax.lax.scan(body, h, (stacked, cache))
-    return h, new_cache
+    return _scan_layers(stacked, cache, cfg, h, layers, apply_sb, paged,
+                        pages, pages_swa)

@@ -148,6 +148,7 @@ from jax.profiler import TraceAnnotation
 from repro import codecs as codecs_lib
 from repro.configs.base import ModelConfig
 from repro.models import lm as lm_lib
+from repro.models import stack as stack_lib
 from repro.models.paging import PagedLayout
 from repro.serving import spec as spec_lib
 from repro.serving.paging import PageAllocator
@@ -550,9 +551,8 @@ class BatchedEngine:
                 return jax.tree.map(z, subtree)
 
             def zero_block(block, axis):
-                if paged is None:
-                    return zero(block, axis)
-                return {key: (sub if key.rsplit("_", 1)[-1] in ("attn", "mla")
+                return {key: (sub if stack_lib.is_pool(key.rsplit("_", 1)[-1],
+                                                       paged)
                               else zero(sub, axis))
                         for key, sub in block.items()}
 
