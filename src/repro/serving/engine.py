@@ -112,8 +112,9 @@ Measurement, always in place and cheap when nobody looks:
   state reads and uploads);
 * counters in ``stats``: ``admitted`` and ``queue_wait_s`` (seconds from
   ``submit``, or the re-queue after an eviction, to admission),
-  ``prefill_tokens`` and ``prefill_rows`` (prompt tokens and rows each
-  prefill chunk dispatched), and for a paged pool ``kv_written_page_s``,
+  ``prefill_tokens`` and ``prefill_rows`` (the prompt tokens each prefill
+  chunk carried, and the rows it computed: its dispatched slot groups'
+  slots x ``chunk_size``), and for a paged pool ``kv_written_page_s``,
   ``kv_reserved_page_s`` and ``kv_pool_page_s`` (page-seconds between
   boundary state reads, each interval weighted by the pages written,
   reserved and in the pool at its start);
@@ -168,6 +169,87 @@ def _codec_execution_mode(codec) -> str:
     if hasattr(codec, "execution_mode"):
         return codec.execution_mode()
     return "unknown"
+
+
+def _group_rows(codec, num_slots: int) -> int:
+    """Slots in one codec group of a prefill chunk: the codec superposes R
+    consecutive slots at each position (``lm.chunk_forward``), so R rows
+    (1 without a codec) are the fewest a chunk can compute apart from the
+    rest.  When R does not divide the slots the groups wrap across
+    positions, and only all the slots together stand apart."""
+    R = getattr(codec, "R", 1)
+    return R if num_slots % R == 0 else num_slots
+
+
+def _group_counts(n: int) -> list[int]:
+    """The slot-group counts a prefill chunk of ``n`` groups dispatches:
+    the powers of two below ``n``, and ``n``."""
+    return sorted({min(1 << j, n) for j in range((n - 1).bit_length() + 1)})
+
+
+def _slot_axes(cache, paged) -> dict:
+    """The slot axis of every leaf of the decode cache, known by KEY — no
+    shape guessing against dims that happen to equal num_slots (heads,
+    cache length, ...): "stack" leaves carry (num_superblocks, B, ...),
+    "first" leaves and the page tables ("pages", "pages_swa") (B, ...).
+    -1 marks a leaf every slot shares: the paged pools (attn/mla leaves on
+    the paged layout, ``stack.is_pool``) and "memory" (encoder output)."""
+    def block(blk, axis):
+        return {key: jax.tree.map(
+                    lambda _: -1 if stack_lib.is_pool(key.rsplit("_", 1)[-1],
+                                                      paged) else axis, sub)
+                for key, sub in blk.items()}
+
+    axes = jax.tree.map(lambda _: -1, cache)
+    axes["stack"] = block(cache["stack"], 1)
+    if "first" in cache:
+        axes["first"] = block(cache["first"], 0)
+    for key in ("pages", "pages_swa"):
+        if key in cache:
+            axes[key] = 0
+    return axes
+
+
+def prefill_groups(params, cache, tokens, pos, cfg: ModelConfig, *, groups,
+                   width: int, codec=None, codec_params=None, valid, paged=None):
+    """``lm.prefill_chunk`` over the rows of the slot groups ``groups``
+    alone; returns (logits (B,V), new_cache) like it, zero logits in the
+    rows left out.
+
+    ``groups`` (k,) int32, ascending, or None for every row; group g is
+    slots ``g*width ... g*width+width-1`` (``width`` from
+    :func:`_group_rows`).  Every row ``valid`` marks must lie in one.  The
+    per-slot cache leaves (:func:`_slot_axes`), page tables, tokens, mask
+    and positions of those rows are gathered, and the per-slot leaves
+    scattered back; the shared pools are written in place through the
+    gathered tables.  Gathered groups keep slot order, so each codec
+    group holds exactly its slots' rows, and a group left out, all
+    invalid, adds only zeros to no one's superposition: every row comes
+    out as it would from the whole chunk."""
+    if groups is None:
+        return lm_lib.prefill_chunk(params, cache, tokens, pos, cfg,
+                                    codec=codec, codec_params=codec_params,
+                                    valid=valid, paged=paged)
+    rows = (groups[:, None] * width + jnp.arange(width)).reshape(-1)
+    axes = _slot_axes(cache, paged)
+    part, sub = lm_lib.prefill_chunk(
+        params, jax.tree.map(
+            lambda ax, a: a if ax < 0 else jnp.take(
+                a, rows, axis=ax, indices_are_sorted=True, unique_indices=True),
+            axes, cache),
+        tokens[rows], pos[rows], cfg, codec=codec, codec_params=codec_params,
+        valid=valid[rows], paged=paged)
+
+    def put(ax, a, b):
+        if ax < 0:
+            return b                   # a pool, written through the tables
+        return a.at[(slice(None),) * ax + (rows,)].set(
+            b, indices_are_sorted=True, unique_indices=True)
+
+    logits = jnp.zeros((tokens.shape[0], part.shape[-1]), part.dtype)
+    return (logits.at[rows].set(part, indices_are_sorted=True,
+                                unique_indices=True),
+            jax.tree.map(put, axes, cache, sub))
 
 
 @dataclasses.dataclass
@@ -476,6 +558,8 @@ class BatchedEngine:
         self._adaptive = isinstance(self.codec, codecs_lib.AdaptiveC3SL)
         self.state = self._init_state()
         self._build_programs()
+        # buckets whose prefill program is traced at every group count
+        self._prefill_warm: set = set()
         # opt-in runtime invariant checks (repro.analysis.sanitize); None
         # in production — every check costs host syncs or extra dispatches
         self._sanitizer = None
@@ -535,31 +619,23 @@ class BatchedEngine:
                                 self._make_spec_program(c, cp, dc, dp, k)
 
         def reset_fn(cache, mask):
-            """Layout-aware zeroing of the rows `mask` marks.  The cache
-            layout is known by KEY: "stack" leaves carry (num_superblocks,
-            B, ...), "first" leaves (B, ...), "memory" (encoder output) is
-            never per-slot state — no shape guessing against dims that
-            happen to equal num_slots (heads, cache length, ...).  Paged
-            pools (attn/mla leaves) are left alone: reads past a slot's
-            written positions are masked, so stale pages are invisible;
-            only per-slot recurrent state needs zeroing."""
-            def zero(subtree, axis):
-                def z(leaf):
-                    m = mask.reshape((1,) * axis + (-1,)
-                                     + (1,) * (leaf.ndim - axis - 1))
-                    return jnp.where(m, 0, leaf)
-                return jax.tree.map(z, subtree)
+            """Zero the rows `mask` marks in every per-slot leaf of
+            "stack" and "first" (:func:`_slot_axes`).  The page tables are
+            the host's to write.  Paged pools are left alone: reads past a
+            slot's written positions are masked, so stale pages are
+            invisible; only per-slot recurrent state needs zeroing."""
+            def zero(axis, leaf):
+                if axis < 0:
+                    return leaf
+                m = mask.reshape((1,) * axis + (-1,)
+                                 + (1,) * (leaf.ndim - axis - 1))
+                return jnp.where(m, 0, leaf)
 
-            def zero_block(block, axis):
-                return {key: (sub if stack_lib.is_pool(key.rsplit("_", 1)[-1],
-                                                       paged)
-                              else zero(sub, axis))
-                        for key, sub in block.items()}
-
+            axes = _slot_axes(cache, paged)
             new = dict(cache)
-            new["stack"] = zero_block(cache["stack"], 1)
-            if "first" in cache:
-                new["first"] = zero_block(cache["first"], 0)
+            for key in ("stack", "first"):
+                if key in cache:
+                    new[key] = jax.tree.map(zero, axes[key], cache[key])
             return new
 
         self._reset = jax.jit(reset_fn, donate_argnums=(0,))
@@ -625,14 +701,22 @@ class BatchedEngine:
 
             return jax.lax.while_loop(cond, body, (jnp.int32(0), cache, state))
 
-        def prefill_fn(params, cache, state, tokens, valid, completes, key):
+        width = _group_rows(codec, self.num_slots)
+
+        def prefill_fn(params, cache, state, tokens, valid, completes, key,
+                       groups=None):
             """Ingest one prompt chunk for the rows `valid` marks; rows whose
             prompt ends in this chunk (`completes`) commit their first
-            generated token from the last prompt position's logits."""
-            logits, cache = lm_lib.prefill_chunk(
-                params, cache, tokens, state["pos"], cfg,
-                codec=codec, codec_params=codec_params, valid=valid,
-                paged=paged)
+            generated token from the last prompt position's logits.
+
+            `tokens`, `valid` and `completes` are indexed by slot; only the
+            rows of the slot groups `groups` (ascending, None = all) are
+            computed, and every prefilling slot must lie in one of them
+            (:func:`prefill_groups`).  One trace per group count."""
+            logits, cache = prefill_groups(
+                params, cache, tokens, state["pos"], cfg, groups=groups,
+                width=width, codec=codec, codec_params=codec_params,
+                valid=valid, paged=paged)
             nxt = jnp.where(completes, pick(logits, key), state["last_tok"])
             B, cap = state["out_buf"].shape
             col = jnp.where(completes, jnp.minimum(state["out_len"], cap - 1), cap)
@@ -827,14 +911,14 @@ class BatchedEngine:
             return 0
         return codecs_lib.payload_wire_bytes(c, c.payload_shape(self.num_slots))
 
-    def _chunk_wire_bytes(self) -> int:
-        """Cut-layer bytes ONE prefill chunk ships (the sequence-grouped 3-D
-        payload: chunk_size positions x num_slots/R groups x D)."""
+    def _chunk_wire_bytes(self, rows: int) -> int:
+        """Cut-layer bytes ONE prefill chunk of `rows` dispatched slots
+        ships (the sequence-grouped 3-D payload: chunk_size positions x
+        rows/R groups x D)."""
         c = self._current_codec()
         if c is None:
             return 0
-        shape = codecs_lib.chunk_payload_shape(c, self.num_slots,
-                                               self.chunk_size)
+        shape = codecs_lib.chunk_payload_shape(c, rows, self.chunk_size)
         return codecs_lib.payload_wire_bytes(c, shape)
 
     def _draft_round_wire_bytes(self, k: int) -> int:
@@ -1146,9 +1230,12 @@ class BatchedEngine:
                    for s in self.slots)
 
     def _prefill_one_chunk(self):
-        """One chunk of up to chunk_size prompt tokens for EVERY slot still
+        """One chunk of up to chunk_size prompt tokens for every slot still
         prefilling, in a single dispatch (ragged tails padded under the
-        length mask; rows not prefilling are fully masked)."""
+        length mask).  The dispatch computes only the codec slot groups
+        (:func:`_group_rows`) that hold a prefilling slot, padded up to a
+        power-of-two count with groups whose rows are all masked
+        (:meth:`_dispatch_groups`)."""
         with TraceAnnotation("engine.prefill_chunk"):
             B, C = self.num_slots, self.chunk_size
             tokens = np.zeros((B, C), np.int32)
@@ -1171,22 +1258,54 @@ class BatchedEngine:
                     (i, self.slots[i].req.uid, self.slots[i].ingested - n, n)
                     for i, n in fed]))
             bucket = self._bucket()
+            width = _group_rows(self._current_codec(), B)
+            groups, k = self._dispatch_groups([i for i, _ in fed], width)
             with TraceAnnotation("engine.device"):
+                if bucket not in self._prefill_warm:
+                    self._warm_prefill(bucket, width)
                 self.rng, key = jax.random.split(self.rng)
                 self.cache, self.state = self._programs[bucket]["prefill"](
                     self.params, self.cache, self.state, jnp.asarray(tokens),
-                    jnp.asarray(valid), jnp.asarray(completes), key)
+                    jnp.asarray(valid), jnp.asarray(completes), key, groups)
             self.stats["dispatches"] += 1
             self.stats["prefill_chunks"] += 1
             self.stats["prefill_tokens"] += sum(n for _, n in fed)
-            self.stats["prefill_rows"] += B * C
-            self._account_fwd_bytes(self._chunk_wire_bytes())
+            self.stats["prefill_rows"] += k * width * C
+            self._account_fwd_bytes(self._chunk_wire_bytes(k * width))
             if bucket is not None:
                 self.r_served[bucket] += 1
             if completes.any():
                 # the completing dispatch commits the row's first token; the
                 # next boundary reads it back (and stamps t_first there)
                 self._dirty = True
+
+    def _dispatch_groups(self, slots, width: int):
+        """(groups, k) for a prefill chunk whose rows ``slots`` prefill:
+        the ascending int32 indices of the slot groups of ``width`` that
+        hold one, padded with groups that hold none up to the next count
+        in :func:`_group_counts`, and that count.  None for every group."""
+        n = self.num_slots // width
+        hit = sorted({i // width for i in slots})
+        k = min(1 << (len(hit) - 1).bit_length(), n)
+        if k == n:
+            return None, n
+        pad = [g for g in range(n) if g not in hit][:k - len(hit)]
+        return jnp.asarray(sorted(hit + pad), jnp.int32), k
+
+    def _warm_prefill(self, bucket, width: int):
+        """Trace the bucket's prefill program at every group count before
+        its first chunk, so that no later chunk compiles: one dispatch per
+        count with every row masked, which writes nothing and leaves the
+        state as it is."""
+        B, C = self.num_slots, self.chunk_size
+        n = B // width
+        for k in _group_counts(n):
+            groups = None if k == n else jnp.arange(k, dtype=jnp.int32)
+            self.cache, self.state = self._programs[bucket]["prefill"](
+                self.params, self.cache, self.state,
+                jnp.zeros((B, C), jnp.int32), jnp.zeros((B, C), bool),
+                jnp.zeros((B,), bool), self.rng, groups)
+        self._prefill_warm.add(bucket)
 
     def _retire_done(self, st, now: float | None = None) -> bool:
         """Retire every slot whose done flag is set in the host state copy

@@ -75,7 +75,8 @@ def test_counters_and_record_exact(setup):
     assert b.done and not eng.queue and not eng.active
     s = eng.stats
     assert (s["admitted"], s["queue_wait_s"]) == (2, 1.0 + 3.0)
-    assert (s["prefill_tokens"], s["prefill_rows"]) == (6 + 8 + 2, 3 * 2 * 8)
+    # each chunk computes slot 0's group alone (no codec: one slot a group)
+    assert (s["prefill_tokens"], s["prefill_rows"]) == (6 + 8 + 2, 3 * 1 * 8)
     # page-seconds between reads at t = 1, 3, 4, 6, each interval weighted
     # by the pages at its start: written 0, 0, 3; reserved 2, 4, 4
     assert s["kv_written_page_s"] == 2 * 3
@@ -107,7 +108,10 @@ def test_record_is_off_by_default_and_counts_every_slot(setup):
     s = eng.stats
     assert s["admitted"] == 3 and s["queue_wait_s"] > 0
     assert s["prefill_tokens"] == 5 + 9 + 13
-    assert s["prefill_rows"] == s["prefill_chunks"] * 2 * 8
+    # chunks: uids 0 and 1 (5 + 8 tokens, both slots), uid 1's last token,
+    # then uid 2's 8 + 5 alone; a chunk computes only its prefilling slots
+    assert s["prefill_chunks"] == 4
+    assert s["prefill_rows"] == (2 + 1 + 1 + 1) * 8
     # a contiguous cache has no page pool to integrate
     assert s["kv_pool_page_s"] == 0.0
 

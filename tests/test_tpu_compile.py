@@ -116,10 +116,11 @@ def _pool_sized(lead: str) -> bool:
 @pytest.mark.filterwarnings("ignore:kv_read='kernel'")
 def test_serving_programs_update_pools_in_place(one_chip, compiled_kernels,
                                                 monkeypatch):
-    """The decode window and the prefill chunk, paged with the kernel read
-    and the Pallas C3-SL codec at the cut, keep each KV pool in one buffer:
-    no split, concatenate, per-layer slice or update of a pool is lowered,
-    and neither program needs as many temporary bytes as one pool leaf."""
+    """The decode window and the prefill chunk (of every slot group, and
+    of one), paged with the kernel read and the Pallas C3-SL codec at the
+    cut, keep each KV pool in one buffer: no split, concatenate, per-layer
+    slice or update of a pool is lowered, and no program needs as many
+    temporary bytes as one pool leaf."""
     from repro.configs.base import ModelConfig
     from repro.models import lm as lm_lib
     from repro.serving.engine import BatchedEngine
@@ -156,6 +157,13 @@ def test_serving_programs_update_pools_in_place(one_chip, compiled_kernels,
             _spec(one_chip, (slots, chunk), jnp.bool_),
             _spec(one_chip, (slots,), jnp.bool_),
             _spec(one_chip, (2,), jnp.uint32)),
+        # the chunk that computes one of the two slot groups
+        "prefill_one_group": progs["prefill"].lower(
+            p, c, st, _spec(one_chip, (slots, chunk), jnp.int32),
+            _spec(one_chip, (slots, chunk), jnp.bool_),
+            _spec(one_chip, (slots,), jnp.bool_),
+            _spec(one_chip, (2,), jnp.uint32),
+            _spec(one_chip, (1,), jnp.int32)),
     }
     leaf_bytes = 4 * L * N * ps * KV * hd
     for name, low in lowered.items():
