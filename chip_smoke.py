@@ -144,8 +144,8 @@ def make_engine(params, cfg, seed: int, *, link: str, kv_read: str):
         warnings.simplefilter("ignore")
         return BatchedEngine(params, cfg, num_slots=SLOTS, max_len=MAX_LEN,
                              codec=link, greedy=True, seed=seed,
-                             prefill_mode="chunked", kv_layout="paged",
-                             page_size=PAGE, kv_read=kv_read)
+                             kv_layout="paged", page_size=PAGE,
+                             kv_read=kv_read)
 
 
 def run_direct(eng, prompts) -> dict[str, list[list[int]]]:

@@ -61,7 +61,7 @@ def _run_engine(cfg, params, args):
     dt = time.time() - t0
     gen = sum(len(r.out) for r in done)
     total = gen + args.requests * args.prompt_len
-    print(f"arch={cfg.name} engine mode={args.prefill_mode} "
+    print(f"arch={cfg.name} engine "
           f"slots={args.batch} chunk={eng.chunk_size} sync={eng.sync_every} "
           f"kv={args.kv_layout} interleave={eng.interleave} "
           f"codec={eng.codec.spec() if eng.codec is not None else 'none'}")
@@ -135,7 +135,6 @@ def _build_engine(cfg, params, args):
                         codec_params=(codec.init(jax.random.PRNGKey(7))
                                       if codec is not None else None),
                         greedy=args.greedy, seed=args.seed,
-                        prefill_mode=args.prefill_mode,
                         chunk_size=args.chunk_size, sync_every=args.sync_every,
                         kv_layout=args.kv_layout, page_size=args.page_size,
                         num_pages=args.num_pages, interleave=args.interleave,
@@ -229,9 +228,6 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--chunk-size", type=int, default=16)
     ap.add_argument("--sync-every", type=int, default=8)
-    ap.add_argument("--prefill-mode", choices=["chunked", "decode"],
-                    default="chunked",
-                    help="'decode' = legacy prefill-as-decode baseline")
     ap.add_argument("--kv-layout", choices=["contiguous", "paged"],
                     default="contiguous",
                     help="'paged' = shared page pool + per-slot page tables "

@@ -5,24 +5,17 @@ slot advances at its OWN position.  When a sequence finishes (EOS or
 max_new_tokens), its slot is recycled for the next queued request
 mid-flight — no draining the batch.
 
-Two prefill modes:
-
-* ``"chunked"`` (default) — the fast path.  Prompts are ingested C tokens
-  per dispatch through ``lm.prefill_chunk`` (ragged tails padded under a
-  length mask), so a length-L prompt costs ceil(L/C) dispatches instead of
-  L.  Slot state (positions, last token, done flags, output buffer) lives
-  ON DEVICE and is advanced inside the jitted step with `jnp.where`
-  masking; decode runs in jitted WINDOWS — a `lax.while_loop` of up to
-  ``sync_every`` fused steps per dispatch that exits device-side the
-  moment no slot is live, so a drained batch never pays for the rest of
-  its window.  The Python loop syncs with the device only per window and
-  on admit/retire boundaries.  Cache and state buffers are donated to the
-  jitted programs, so XLA updates them in place instead of copying the KV
-  cache every step.
-
-* ``"decode"`` — the original prefill-as-decode path (one token, one
-  dispatch, one host sync per engine step), kept as the measurable
-  baseline for benchmarks/bench_serving.py and for equivalence tests.
+Prompts are ingested C tokens per dispatch through ``lm.prefill_chunk``
+(ragged tails padded under a length mask), so a length-L prompt costs
+ceil(L/C) dispatches instead of L.  Slot state (positions, last token,
+done flags, output buffer) lives ON DEVICE and is advanced inside the
+jitted programs with `jnp.where` masking; decode runs in jitted WINDOWS —
+a `lax.while_loop` of up to ``sync_every`` fused steps per dispatch that
+exits device-side the moment no slot is live, so a drained batch never
+pays for the rest of its window.  The Python loop syncs with the device
+only per window and on admit/retire boundaries.  Cache and state buffers
+are donated to the jitted programs, so XLA updates them in place instead
+of copying the KV cache every step.
 
 Two KV-cache layouts (``kv_layout``):
 
@@ -85,7 +78,7 @@ worst-case ``prompt + max_new`` page reservation freed — instead of the
 reservation being held until the next boundary's retire sweep;
 ``pool_accounting()`` exposes the free/in-use split the tests pin.
 
-Slot preemption (``preemption=True``, chunked mode): when the head of the
+Slot preemption (``preemption=True``): when the head of the
 queue is blocked on pages (or on a free slot) and outranks running work
 (``Request.priority``), the boundary EVICTS strictly-lower-priority slots
 — least progress first — frees their reservations, and re-queues the
@@ -123,15 +116,15 @@ Measurement, always in place and cheap when nobody looks:
   the grouping that decided each request's superposed cut features.
 
 The C3-SL codec applies to each step's cut-layer features across the
-active slots; on the chunked path the features are grouped PER POSITION
-(`sequence_group_encode` layout), the same group shape as the decode
-path's batch-wise groups.  Outputs match the decode path token-for-token
-when slot occupancy matches too (full batch, equal-length prompts,
-lockstep admission); empty slots or ragged prompts contribute different
-padding features to the superposition on the two paths, so there outputs
-agree only up to codec cross-talk — the price batch-wise compression
-always puts on occupancy changes.  The same caveat applies to paged vs
-contiguous under a codec: non-live rows read (masked-out but
+active slots; a prefill chunk groups the features PER POSITION
+(`sequence_group_encode` layout), the same group shape as a decode step's
+batch-wise groups.  Outputs match a token-by-token ``lm.decode_step``
+ingest token-for-token when slot occupancy matches too (full batch,
+equal-length prompts, lockstep admission); empty slots or ragged prompts
+contribute different padding features to the superposition on the two,
+so there outputs agree only up to codec cross-talk — the price batch-wise
+compression always puts on occupancy changes.  The same caveat applies
+to paged vs contiguous under a codec: non-live rows read (masked-out but
 codec-visible) stale pages instead of zeroed strips.
 """
 from __future__ import annotations
@@ -278,9 +271,7 @@ class Request:
 @dataclasses.dataclass
 class _Slot:
     req: Request | None = None
-    pos: int = 0             # next cache position to write (legacy mode)
-    in_prompt: int = 0       # tokens of the prompt already ingested (legacy)
-    ingested: int = 0        # tokens of the feed already ingested (chunked)
+    ingested: int = 0        # tokens of the feed already ingested
     # what this residency must ingest before decoding: the prompt, plus —
     # after an eviction — the tokens already emitted, so a re-admitted
     # request re-prefills its full generated-so-far context and greedy
@@ -344,9 +335,10 @@ class BatchedEngine:
             if codec_params is not None:
                 codec_params = codec.fwd_params(codec_params)
             codec = codec.fwd.codec
-        if prefill_mode not in ("chunked", "decode"):
-            raise ValueError(f"unknown prefill_mode {prefill_mode!r} "
-                             "(expected 'chunked' | 'decode')")
+        if prefill_mode != "chunked":
+            raise ValueError(f"prefill_mode={prefill_mode!r}: "
+                             "prefill_mode='decode' was removed; the engine "
+                             "has one prefill path ('chunked')")
         if kv_layout not in ("contiguous", "paged"):
             raise ValueError(f"unknown kv_layout {kv_layout!r} "
                              "(expected 'contiguous' | 'paged')")
@@ -358,10 +350,6 @@ class BatchedEngine:
                 "kv_read='kernel' requires kv_layout='paged': the Pallas "
                 "paged-attention kernel is a page-table walk, and a "
                 "contiguous cache has no table to walk")
-        if preemption and prefill_mode != "chunked":
-            raise ValueError("preemption requires prefill_mode='chunked' "
-                             "(eviction re-queues the request for chunked "
-                             "re-prefill of its generated context)")
         # ---- speculative decoding (repro.serving.spec) -------------------
         # spec_decode may be a SpecConfig, True (defaults), or None; a link
         # spec carrying a "draft:" segment auto-enables it with defaults.
@@ -371,10 +359,6 @@ class BatchedEngine:
             spec_decode = SpecConfig()
         self.spec_cfg: SpecConfig | None = spec_decode
         if spec_decode is not None:
-            if prefill_mode != "chunked":
-                raise ValueError(
-                    "spec_decode requires prefill_mode='chunked': the verify "
-                    "round is a k-position chunk dispatch")
             if not greedy:
                 raise ValueError(
                     "spec_decode requires greedy=True: greedy verification "
@@ -413,7 +397,6 @@ class BatchedEngine:
         self.max_len = max_len
         self.eos_id = eos_id
         self.greedy = greedy
-        self.prefill_mode = prefill_mode
         self.kv_layout = kv_layout
         self.interleave = max(0, interleave)
         # each ring slot must be written at most once per chunk (SWA caches
@@ -446,19 +429,17 @@ class BatchedEngine:
                 fallbacks.append("MLA latent reads")
             if cfg.first_dense_layers:
                 fallbacks.append("the unstacked first-dense superblock")
-            if prefill_mode == "chunked":
-                fallbacks.append("chunked-prefill reads")
+            fallbacks.append("chunked-prefill reads")
             if self.spec_cfg is not None:
                 fallbacks.append("speculative verify/commit reads")
-            if fallbacks:
-                # loud by design: the silent-fallback bug class this tier
-                # fixes.  The uncovered reads stay on gather_pages and are
-                # still bit-identical — but the operator must know the
-                # kernel is not serving them.
-                warnings.warn(
-                    "kv_read='kernel': " + ", ".join(fallbacks) + " stay on "
-                    "the gather read path (kernel tier covers stacked GQA "
-                    "decode only)", stacklevel=2)
+            # loud by design: the silent-fallback bug class this tier
+            # fixes.  The uncovered reads stay on gather_pages and are
+            # still bit-identical — but the operator must know the
+            # kernel is not serving them.
+            warnings.warn(
+                "kv_read='kernel': " + ", ".join(fallbacks) + " stay on "
+                "the gather read path (kernel tier covers stacked GQA "
+                "decode only)", stacklevel=2)
         if kv_layout == "paged":
             len_swa = min(max_len, cfg.sliding_window) if cfg.sliding_window else 0
             pps = -(-max_len // page_size)
@@ -641,8 +622,8 @@ class BatchedEngine:
         self._reset = jax.jit(reset_fn, donate_argnums=(0,))
 
     def _make_programs(self, codec, codec_params) -> dict:
-        """One codec's compiled program set: the fused decode window, the
-        chunked-prefill dispatch, and the legacy prefill-as-decode step."""
+        """One codec's compiled program set: the fused decode window and
+        the chunked-prefill dispatch."""
         cfg = self.cfg
         greedy, eos_id, max_len = self.greedy, self.eos_id, self.max_len
         paged, kv_read = self.paged, self.kv_read
@@ -729,17 +710,8 @@ class BatchedEngine:
                            "active": state["active"] | completes,
                            "out_len": out_len, "out_buf": out_buf}
 
-        def legacy_step_fn(params, cache, tokens, pos, key, live):
-            logits, cache = lm_lib.decode_step(params, cache, tokens, pos, cfg,
-                                               codec=codec,
-                                               codec_params=codec_params,
-                                               paged=paged, live=live,
-                                               kv_read=kv_read)
-            return pick(logits[:, -1], key), cache
-
         return {"window": jax.jit(window_fn, donate_argnums=(1, 2)),
-                "prefill": jax.jit(prefill_fn, donate_argnums=(1, 2)),
-                "legacy": jax.jit(legacy_step_fn)}
+                "prefill": jax.jit(prefill_fn, donate_argnums=(1, 2))}
 
     # ------------------------------------------------------------------
     # speculative verify/commit programs (repro.serving.spec)
@@ -998,30 +970,18 @@ class BatchedEngine:
         for i, slot in enumerate(self.slots):
             if slot.req is None or slot.req.uid != uid:
                 continue
-            req = slot.req
             self.stats["withdrawn"] += 1
-            if self.prefill_mode == "chunked":
-                with TraceAnnotation("engine.device"):
-                    st = {k: np.array(v)
-                          for k, v in jax.device_get(self.state).items()}
-                if self.dispatch_record is not None:
-                    self._close_window_record(st)
-                n = int(st["out_len"][i])
-                req.out = [int(t) for t in st["out_buf"][i, :n]]
-                self._fold_spec_counters(i, req, st)
-                st["active"][i] = st["done"][i] = False
-                st["pos"][i] = st["last_tok"][i] = st["out_len"][i] = 0
-                st["out_buf"][i, :] = 0
-                with TraceAnnotation("engine.device"):
-                    self.state = jax.device_put(st)
+            with TraceAnnotation("engine.device"):
+                st = {k: np.array(v)
+                      for k, v in jax.device_get(self.state).items()}
+            if self.dispatch_record is not None:
+                self._close_window_record(st)
+            req = self._vacate(i, st)
+            with TraceAnnotation("engine.device"):
+                self.state = jax.device_put(st)
             self._stream_mark.pop(uid, None)
             req.evictions += 1
             req.done = False
-            slot.req = None
-            slot.feed = []
-            slot.ingested = 0
-            slot.pos = slot.in_prompt = 0
-            self._free_slot_pages(i)
             self._dirty = True
             return req
         return None
@@ -1046,8 +1006,6 @@ class BatchedEngine:
         self._sanitizer = sanitizer
 
     def run(self, max_steps: int = 10_000) -> list[Request]:
-        if self.prefill_mode == "decode":
-            return self._run_legacy(max_steps)
         steps = 0
         while steps < max_steps:
             with TraceAnnotation("engine.tick"):
@@ -1070,8 +1028,6 @@ class BatchedEngine:
         engine is idle (no queued or resident work) — the caller's cue to
         sleep instead of spinning."""
         with TraceAnnotation("engine.tick"):
-            if self.prefill_mode == "decode":
-                return bool(self.step())
             self._boundary()
             if not (self.queue or self.active):
                 return False
@@ -1103,7 +1059,7 @@ class BatchedEngine:
         return self._decode_window(min(self.sync_every, budget))
 
     # ------------------------------------------------------------------
-    # fast path internals
+    # scheduler internals
     # ------------------------------------------------------------------
 
     def _spec_k(self) -> int:
@@ -1322,21 +1278,34 @@ class BatchedEngine:
             if slot.req.t_first is None and st["out_len"][i] > 0:
                 slot.req.t_first = now
             if st["done"][i]:
-                n = int(st["out_len"][i])
-                slot.req.out = [int(t) for t in st["out_buf"][i, :n]]
-                slot.req.done = True
-                self.finished.append(slot.req)
-                self._tokens_decoded += n
-                self._fold_spec_counters(i, slot.req, st)
-                self._stream_mark.pop(slot.req.uid, None)
-                slot.req = None
-                slot.feed = []
-                self._free_slot_pages(i)
-                st["active"][i] = st["done"][i] = False
-                st["pos"][i] = st["last_tok"][i] = st["out_len"][i] = 0
-                st["out_buf"][i, :] = 0
+                req = self._vacate(i, st)
+                req.done = True
+                self.finished.append(req)
+                self._tokens_decoded += len(req.out)
+                self._stream_mark.pop(req.uid, None)
                 touched = True
         return touched
+
+    def _vacate(self, i: int, st) -> Request:
+        """Empty slot ``i`` and return the request it held, with the tokens
+        emitted so far (read from the host state copy ``st``) in
+        ``req.out`` and its speculative counters folded in.  The slot's
+        pages go back to the pool and its row of ``st`` is zeroed, so the
+        caller's upload leaves the slot inert.  Retire, evict and withdraw
+        each add only what is their own."""
+        slot = self.slots[i]
+        req = slot.req
+        n = int(st["out_len"][i])
+        req.out = [int(t) for t in st["out_buf"][i, :n]]
+        self._fold_spec_counters(i, req, st)
+        slot.req = None
+        slot.feed = []
+        slot.ingested = 0
+        self._free_slot_pages(i)
+        st["active"][i] = st["done"][i] = False
+        st["pos"][i] = st["last_tok"][i] = st["out_len"][i] = 0
+        st["out_buf"][i, :] = 0
+        return req
 
     def _fold_spec_counters(self, i: int, req: Request, st):
         """Fold slot i's device-side speculative counters into the request
@@ -1383,21 +1352,10 @@ class BatchedEngine:
         queued work, so a single high-priority arrival cannot starve it).
         On re-admission the request re-prefills prompt + emitted tokens
         (``slot.feed``), so greedy decode resumes bit-identically."""
-        slot = self.slots[i]
-        req = slot.req
-        n = int(st["out_len"][i])
-        req.out = [int(t) for t in st["out_buf"][i, :n]]
+        req = self._vacate(i, st)
         req.evictions += 1
         req.t_queued = self.clock()
         self.stats["evictions"] += 1
-        self._fold_spec_counters(i, req, st)
-        slot.req = None
-        slot.feed = []
-        slot.ingested = 0
-        self._free_slot_pages(i)
-        st["active"][i] = st["done"][i] = False
-        st["pos"][i] = st["last_tok"][i] = st["out_len"][i] = 0
-        st["out_buf"][i, :] = 0
         self.queue.insert(1, req)
 
     def _preempt_for(self, st, head: Request) -> bool:
@@ -1432,7 +1390,7 @@ class BatchedEngine:
         return evicted
 
     def _boundary(self):
-        """Admit/retire boundary: the ONLY place the fast path syncs with
+        """Admit/retire boundary: the ONLY place the engine syncs with
         the device outside the per-window cadence.  In paged mode this is
         also where pages move: retire frees a slot's pages, admission
         waits (FIFO — no overtaking) until the head request's reservation
@@ -1467,7 +1425,8 @@ class BatchedEngine:
                     continue               # room was made — retry the head
                 slot = self.slots[i]
                 slot.req = self.queue.popleft()
-                self._count_admission(slot.req, now)
+                self.stats["admitted"] += 1
+                self.stats["queue_wait_s"] += now - slot.req.t_queued
                 slot.ingested = 0
                 # re-admitted (evicted) requests re-prefill their emitted
                 # tokens too, and resume with out_len/out_buf pre-seeded so
@@ -1502,10 +1461,6 @@ class BatchedEngine:
                         self.cache = self._reset(self.cache,
                                                  jnp.asarray(mask))
 
-    def _count_admission(self, req: Request, now: float):
-        self.stats["admitted"] += 1
-        self.stats["queue_wait_s"] += now - req.t_queued
-
     def _integrate_pages(self, st, now: float):
         """Add the page-seconds since the previous boundary read, weighted
         by the pages at that read, and note the pages at this one (``st``
@@ -1536,7 +1491,7 @@ class BatchedEngine:
         """Turn the dispatch record on (returns the fresh list it fills)
         or off (returns None).
 
-        One entry per codec call of the chunked path, in dispatch order:
+        One entry per codec call, in dispatch order:
         a prefill chunk is ``(t, "P", [(slot, uid, start, n), ...])`` —
         the ``n`` prompt positions from ``start`` that request ``uid`` fed
         through ``slot`` — and each decode step of a window is ``(t, "D",
@@ -1548,9 +1503,9 @@ class BatchedEngine:
         state read the engine makes anyway (a boundary, an early exit or
         a withdraw), from the positions it returns, so recording adds no
         host sync past the one read of positions made here.  Speculative
-        windows and the legacy ``prefill_mode="decode"`` path are not
-        recorded.  The list grows by one entry per step: an operator
-        turns it on for an audit, not for the life of a server."""
+        windows are not recorded.  The list grows by one entry per step:
+        an operator turns it on for an audit, not for the life of a
+        server."""
         self._pending_window = None
         if not on:
             self.dispatch_record = None
@@ -1611,98 +1566,3 @@ class BatchedEngine:
         self.allocator.free(self.slots[i].pages)
         self.slots[i].pages = []
         self._table[i, :] = 0
-
-    # ------------------------------------------------------------------
-    # legacy path (prefill-as-decode, one host sync per token) — kept as
-    # the benchmark baseline and for equivalence tests
-    # ------------------------------------------------------------------
-
-    def _reset_slot_cache(self, idx: int):
-        """Zero one slot's cache rows so a recycled slot starts clean."""
-        mask = np.zeros((self.num_slots,), bool)
-        mask[idx] = True
-        self.cache = self._reset(self.cache, jnp.asarray(mask))
-
-    def _admit(self):
-        for i, slot in enumerate(self.slots):
-            if slot.req is None and self.queue:
-                if not self._alloc_slot_pages(i, self.queue[0]):
-                    break
-                slot.req = self.queue.popleft()
-                self._count_admission(slot.req, self.clock())
-                slot.pos = 0
-                slot.in_prompt = 0
-                slot.feed = list(slot.req.prompt) + list(slot.req.out)
-                if self.paged is not None:
-                    self.cache = {**self.cache,
-                                  "pages": jnp.asarray(self._table)}
-                self._reset_slot_cache(i)
-
-    def step(self):
-        """One legacy engine step: every active slot ingests/decodes one
-        token ("prefill as decode"), then a host sync."""
-        self._admit()
-        if self.active == 0:
-            return False
-        tokens = np.zeros((self.num_slots, 1), np.int32)
-        pos = np.zeros((self.num_slots,), np.int32)
-        occupied = np.zeros((self.num_slots,), bool)
-        for i, s in enumerate(self.slots):
-            if s.req is None:
-                continue
-            occupied[i] = True
-            if s.in_prompt < len(s.feed):
-                tokens[i, 0] = s.feed[s.in_prompt]
-            else:
-                tokens[i, 0] = s.req.out[-1]
-            pos[i] = s.pos
-        self.rng, key = jax.random.split(self.rng)
-        # contiguous: unmasked writes (empty rows scribble on their own
-        # zeroed strip, exactly the PR2 baseline the equivalence tests pin);
-        # paged: empty rows hold no pages, so their writes MUST be masked
-        live = jnp.asarray(occupied) if self.paged is not None else None
-        bucket = self._bucket()
-        with TraceAnnotation("engine.device"):
-            nxt, self.cache = self._programs[bucket]["legacy"](
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(pos), key, live)
-            nxt = np.asarray(nxt)
-        self.stats["dispatches"] += 1
-        # one fused batch step per dispatch — same unit as the chunked
-        # path's decode_steps (NOT per-slot generated tokens)
-        self.stats["decode_steps"] += 1
-        self._account_fwd_bytes(self._step_wire_bytes())
-        if bucket is not None:
-            self.r_served[bucket] += 1
-        for i, s in enumerate(self.slots):
-            if s.req is None:
-                continue
-            s.pos += 1
-            fed_prompt = s.in_prompt < len(s.feed)
-            if fed_prompt:
-                s.in_prompt += 1
-            # the prediction counts once the WHOLE prompt is in: the last
-            # prompt token's logits give the first generated token
-            if not fed_prompt or s.in_prompt == len(s.feed):
-                tok = int(nxt[i])
-                s.req.out.append(tok)
-                if s.req.t_first is None:
-                    s.req.t_first = self.clock()
-                self._tokens_decoded += 1
-                if (self.eos_id is not None and tok == self.eos_id) \
-                        or len(s.req.out) >= s.req.max_new_tokens \
-                        or s.pos >= self.max_len:
-                    s.req.done = True
-            if s.req.done:
-                self.finished.append(s.req)
-                s.req = None
-                self._free_slot_pages(i)
-        return True
-
-    def _run_legacy(self, max_steps: int) -> list[Request]:
-        steps = 0
-        while (self.queue or self.active) and steps < max_steps:
-            with TraceAnnotation("engine.tick"):
-                self.step()
-            steps += 1
-        return self.finished

@@ -1,4 +1,4 @@
-"""Chunked prefill: token-level equivalence vs prefill-as-decode, ragged
+"""Chunked prefill: token-level equivalence vs token-by-token decode, ragged
 chunk tails, masked rows, ring buffers, stateful layers, and engine-level
 equivalence (greedy, with and without the C3-SL codec)."""
 import math
@@ -176,62 +176,97 @@ def test_prefill_masked_rows_are_untouched():
 
 
 # ---------------------------------------------------------------------------
-# engine-level equivalence (chunked + device-resident stepping vs legacy)
+# engine-level equivalence (chunked prefill + device-resident decode windows
+# vs a token-by-token greedy decode)
 # ---------------------------------------------------------------------------
 
-def _engine_pair(cfg, params, **kw):
-    a = BatchedEngine(params, cfg, prefill_mode="chunked", **kw)
-    b = BatchedEngine(params, cfg, prefill_mode="decode", **kw)
-    return a, b
+def _greedy_reference(params, cfg, prompts, max_new, *, width, max_len,
+                      eos_id=None, codec=None, codec_params=None):
+    """Greedy decode one token a step through ``lm.decode_step``: the
+    prompts run as the rows of batches of ``width`` (the last padded with
+    empty rows), each row fed its prompt, then its own argmax until EOS,
+    its ``max_new`` or ``max_len``.  Finished and empty rows are masked
+    out (``live``).  Returns each prompt's generated tokens."""
+    step = jax.jit(lambda c, t, p, live: lm_lib.decode_step(
+        params, c, t, p, cfg, codec=codec, codec_params=codec_params,
+        live=live))
+    outs = []
+    for lo in range(0, len(prompts), width):
+        rows = prompts[lo:lo + width]
+        cache = lm_lib.init_decode_cache(params, cfg, width, max_len)
+        pos = np.zeros((width,), np.int32)
+        out = [[] for _ in range(width)]
+        live = np.arange(width) < len(rows)
+        while live.any():
+            toks = np.zeros((width, 1), np.int32)
+            for b, p in enumerate(rows):
+                toks[b, 0] = p[pos[b]] if pos[b] < len(p) else out[b][-1]
+            logits, cache = step(cache, jnp.asarray(toks), jnp.asarray(pos),
+                                 jnp.asarray(live))
+            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+            for b, p in enumerate(rows):
+                if not live[b]:
+                    continue
+                pos[b] += 1
+                if pos[b] >= len(p):
+                    out[b].append(int(nxt[b]))
+                    live[b] = not (nxt[b] == eos_id
+                                   or len(out[b]) >= max_new[lo + b]
+                                   or pos[b] >= max_len)
+        outs += out[:len(rows)]
+    return outs
 
 
-def test_engine_chunked_equals_decode_mode_ragged_recycling():
+@pytest.mark.parametrize("width", [1, 3])
+def test_engine_equals_greedy_reference_ragged_recycling(width):
     """6 ragged requests through 3 slots (mid-flight recycling, chunk tails
-    of every length): the fast path emits bit-identical greedy outputs."""
+    of every length, EOS): the engine emits the reference's greedy outputs
+    exactly, whichever batch rows the reference runs them in."""
     cfg = _cfg()
     params = lm_lib.init_lm_params(jax.random.PRNGKey(0), cfg)
-    fast, slow = _engine_pair(cfg, params, num_slots=3, max_len=32,
-                              eos_id=1, chunk_size=4, sync_every=3)
+    eng = BatchedEngine(params, cfg, num_slots=3, max_len=32, eos_id=1,
+                        chunk_size=4, sync_every=3)
     lens = [3, 5, 9, 2, 7, 4]
     rng = np.random.RandomState(17)
     reqs = [list(map(int, rng.randint(2, cfg.vocab_size, n))) for n in lens]
-    for eng in (fast, slow):
-        for u, p in enumerate(reqs):
-            eng.submit(Request(uid=u, prompt=list(p), max_new_tokens=4 + u % 3))
-    out_fast = {r.uid: r.out for r in fast.run()}
-    out_slow = {r.uid: r.out for r in slow.run()}
-    assert len(out_fast) == len(out_slow) == len(reqs)
-    assert out_fast == out_slow
+    max_new = [4 + u % 3 for u in range(len(reqs))]
+    for u, p in enumerate(reqs):
+        eng.submit(Request(uid=u, prompt=list(p), max_new_tokens=max_new[u]))
+    got = {r.uid: r.out for r in eng.run()}
+    ref = _greedy_reference(params, cfg, reqs, max_new, width=width,
+                            max_len=32, eos_id=1)
+    assert got == dict(enumerate(ref))
 
 
-def test_engine_chunked_equals_decode_mode_with_codec():
+def test_engine_equals_greedy_reference_with_codec():
     """Full batch of equal-length prompts through the C3-SL codec: the
-    per-position sequence groups coincide with the decode path's batch
+    per-position sequence groups coincide with a decode step's batch
     groups, so outputs match exactly."""
     cfg = _cfg()
     params = lm_lib.init_lm_params(jax.random.PRNGKey(0), cfg)
     codec = codecs_lib.build("c3sl:R=4|int8", D=cfg.d_model)
     cp = codec.init(jax.random.PRNGKey(7))
-    fast, slow = _engine_pair(cfg, params, num_slots=4, max_len=32,
-                              codec=codec, codec_params=cp,
-                              chunk_size=4, sync_every=2)
+    eng = BatchedEngine(params, cfg, num_slots=4, max_len=32, codec=codec,
+                        codec_params=cp, chunk_size=4, sync_every=2)
     rng = np.random.RandomState(19)
     reqs = [list(map(int, rng.randint(1, cfg.vocab_size, 8))) for _ in range(4)]
-    for eng in (fast, slow):
-        for u, p in enumerate(reqs):
-            eng.submit(Request(uid=u, prompt=list(p), max_new_tokens=4))
-    out_fast = {r.uid: r.out for r in fast.run()}
-    out_slow = {r.uid: r.out for r in slow.run()}
-    assert out_fast == out_slow
+    for u, p in enumerate(reqs):
+        eng.submit(Request(uid=u, prompt=list(p), max_new_tokens=4))
+    got = {r.uid: r.out for r in eng.run()}
+    ref = _greedy_reference(params, cfg, reqs, [4] * 4, width=4, max_len=32,
+                            codec=codec, codec_params=cp)
+    assert got == dict(enumerate(ref))
 
 
-def test_engine_prompt_longer_than_chunk_and_sync_window():
+@pytest.mark.parametrize("width", [1, 2])
+def test_engine_prompt_longer_than_chunk_and_sync_window(width):
     """Prompt spanning many chunks + generation spanning many sync windows."""
     cfg = _cfg()
     params = lm_lib.init_lm_params(jax.random.PRNGKey(0), cfg)
-    fast, slow = _engine_pair(cfg, params, num_slots=2, max_len=64,
-                              chunk_size=4, sync_every=5)
+    eng = BatchedEngine(params, cfg, num_slots=2, max_len=64, chunk_size=4,
+                        sync_every=5)
     prompt = list(range(2, 25))                    # 23 tokens -> 6 chunks
-    for eng in (fast, slow):
-        eng.submit(Request(uid=0, prompt=list(prompt), max_new_tokens=12))
-    assert [r.out for r in fast.run()] == [r.out for r in slow.run()]
+    eng.submit(Request(uid=0, prompt=list(prompt), max_new_tokens=12))
+    ref = _greedy_reference(params, cfg, [prompt], [12], width=width,
+                            max_len=64)
+    assert [r.out for r in eng.run()] == ref

@@ -44,8 +44,7 @@ def test_counters_and_record_exact(setup):
     cfg, params = setup
     eng = BatchedEngine(params, cfg, num_slots=2, max_len=32, chunk_size=8,
                         sync_every=8, greedy=True, seed=0,
-                        prefill_mode="chunked", kv_layout="paged",
-                        page_size=4, num_pages=4)
+                        kv_layout="paged", page_size=4, num_pages=4)
     clock = eng.clock = FakeClock()
     record = eng.record_dispatches()
     rng = np.random.RandomState(5)
@@ -96,8 +95,7 @@ def test_counters_and_record_exact(setup):
 def test_record_is_off_by_default_and_counts_every_slot(setup):
     cfg, params = setup
     eng = BatchedEngine(params, cfg, num_slots=2, max_len=32, chunk_size=8,
-                        sync_every=4, greedy=True, seed=0,
-                        prefill_mode="chunked")
+                        sync_every=4, greedy=True, seed=0)
     assert eng.dispatch_record is None
     rng = np.random.RandomState(1)
     for u in range(3):
@@ -154,8 +152,7 @@ def test_front_door_trace_holds_every_span_nested(setup, tmp_path):
     async def go():
         eng = BatchedEngine(params, cfg, num_slots=2, max_len=48,
                             chunk_size=8, sync_every=4, greedy=True, seed=0,
-                            prefill_mode="chunked", kv_layout="paged",
-                            page_size=8, num_pages=12)
+                            kv_layout="paged", page_size=8, num_pages=12)
         server = FrontDoorServer(eng, auto_tick=False)
         host, port = await server.start()
         client = await FrontDoorClient.open(host, port, tenant="t0",

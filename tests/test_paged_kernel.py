@@ -275,8 +275,7 @@ def test_fallback_warning_is_loud():
     cfg = _cfg()
     params = lm_lib.init_lm_params(jax.random.PRNGKey(0), cfg)
     with pytest.warns(UserWarning, match="stay on the gather read path"):
-        BatchedEngine(params, cfg, kv_layout="paged", kv_read="kernel",
-                      prefill_mode="chunked")
+        BatchedEngine(params, cfg, kv_layout="paged", kv_read="kernel")
 
 
 def test_execution_mode_in_engine_stats():

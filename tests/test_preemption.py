@@ -40,8 +40,7 @@ def _engine(cfg, params, **kw):
     kw.setdefault("max_len", 32)
     kw.setdefault("chunk_size", 8)
     kw.setdefault("sync_every", 4)
-    return BatchedEngine(params, cfg, greedy=True, seed=0,
-                         prefill_mode="chunked", **kw)
+    return BatchedEngine(params, cfg, greedy=True, seed=0, **kw)
 
 
 def _prompt(rng, n, vocab=128):
@@ -235,8 +234,13 @@ def test_eos_early_exit_frees_pages_before_boundary(setup):
     assert acct["free"] == acct["total"]
 
 
-def test_preemption_requires_chunked_prefill(setup):
+def test_prefill_mode_decode_is_refused(setup):
+    """The engine has one prefill path: asking for the removed
+    prefill-as-decode mode is an error, not a silent fallback."""
     cfg, params = setup
-    with pytest.raises(ValueError, match="preemption"):
+    removed = "decode"
+    with pytest.raises(ValueError, match="prefill_mode='decode' was removed"):
         BatchedEngine(params, cfg, num_slots=2, max_len=32,
-                      prefill_mode="decode", preemption=True)
+                      prefill_mode=removed)
+    eng = BatchedEngine(params, cfg, num_slots=2, max_len=32)
+    assert not hasattr(eng, "prefill_mode") and not hasattr(eng, "step")

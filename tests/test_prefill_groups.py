@@ -260,7 +260,7 @@ def test_dispatch_groups(width, slots, groups, k):
     """(d) The groups a chunk dispatches: those holding a prefilling slot,
     ascending, padded with the first empty groups up to a power of two;
     None once that is every group."""
-    eng = _engine("no_codec", prefill_mode="decode")
+    eng = _engine("no_codec")
     got, n = eng._dispatch_groups(slots, width)
     assert n == k
     assert (got if got is None else np.asarray(got).tolist()) == groups

@@ -3,6 +3,7 @@ and equivalence with lockstep decode."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs.base import get_config, reduced
 from repro.models import lm as lm_lib
@@ -101,7 +102,6 @@ def test_submit_rejects_overlong_and_empty_prompts():
     exactly max_len used to be admitted, prefilled, and cut off after one
     token regardless of max_new_tokens (finish_check fires at
     pos >= max_len) — it must be rejected, not silently truncated."""
-    import pytest
     cfg, params, eng = _setup(num_slots=2, max_len=8)
     with pytest.raises(ValueError, match="max_len=8"):
         eng.submit(Request(uid=0, prompt=list(range(1, 10)), max_new_tokens=2))
@@ -118,7 +118,7 @@ def test_submit_rejects_overlong_and_empty_prompts():
     assert len(done) == 1 and len(done[0].out) == 2
 
 
-def test_reset_slot_cache_is_layout_aware():
+def test_slot_reset_is_layout_aware():
     """Regression for the old shape heuristic: with max_len == num_slots an
     UNSTACKED first-dense cache leaf (B, T, ...) has shape[1] == num_slots,
     and `leaf.at[:, idx].set(0)` would zero cache POSITION idx across every
@@ -129,7 +129,7 @@ def test_reset_slot_cache_is_layout_aware():
     n = 8
     eng = BatchedEngine(params, cfg, num_slots=n, max_len=n)
     eng.cache = jax.tree.map(jnp.ones_like, eng.cache)
-    eng._reset_slot_cache(0)
+    eng.cache = eng._reset(eng.cache, jnp.asarray(np.arange(n) == 0))
     first = eng.cache["first"]["l0_0_mla"]["c_kv"]      # (B, T, L), T == B
     assert np.asarray(first[0]).max() == 0.0            # slot 0 cleared
     assert np.asarray(first[1:]).min() == 1.0           # other slots intact
@@ -217,7 +217,12 @@ def test_staggered_positions_are_independent():
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
 
 
-def test_adaptive_pinned_engine_matches_static_greedy_decode():
+@pytest.mark.parametrize("adaptive_spec,static_spec", [
+    ("adaptive:c3sl:R=4,min_R=2", "c3sl:R=2"),
+    ("adaptive:c3sl:R=4,min_R=2|int8", "c3sl:R=2|int8"),
+])
+def test_adaptive_pinned_engine_matches_static_greedy_decode(adaptive_spec,
+                                                              static_spec):
     """AdaptiveC3SL pinned to a constant schedule through a BatchedEngine
     greedy decode is bit-identical to the static codec — including the
     |int8 chain: the engine's per-bucket programs close over the same
@@ -227,38 +232,14 @@ def test_adaptive_pinned_engine_matches_static_greedy_decode():
                   head_dim=32)
     params = lm_lib.init_lm_params(jax.random.PRNGKey(0), cfg)
     reqs = [([5, 17, 23, 2], 5), ([7, 7, 9], 4), ([3, 11], 3)]
-    for adaptive_spec, static_spec in [
-        ("adaptive:c3sl:R=4,min_R=2", "c3sl:R=2"),
-        ("adaptive:c3sl:R=4,min_R=2|int8", "c3sl:R=2|int8"),
-    ]:
-        outs = {}
-        for name, spec in (("static", static_spec), ("adaptive", adaptive_spec)):
-            eng = BatchedEngine(params, cfg, num_slots=2, max_len=32,
-                                codec=spec, greedy=True)
-            if name == "adaptive":
-                eng.codec.pin(2)
-            for u, (p, mn) in enumerate(reqs):
-                eng.submit(Request(uid=u, prompt=list(p), max_new_tokens=mn))
-            outs[name] = {r.uid: r.out for r in eng.run(max_steps=128)}
-            assert len(outs[name]) == len(reqs)
-        assert outs["adaptive"] == outs["static"], adaptive_spec
-
-
-def test_adaptive_engine_legacy_mode_matches_static_too():
-    """Same pinned-schedule equivalence on the prefill-as-decode baseline
-    path (the per-bucket legacy program)."""
-    cfg = reduced(get_config("deepseek-7b"), num_layers=2, d_model=128,
-                  d_ff=256, vocab_size=128, num_heads=4, num_kv_heads=2,
-                  head_dim=32)
-    params = lm_lib.init_lm_params(jax.random.PRNGKey(0), cfg)
     outs = {}
-    for name, spec in (("static", "c3sl:R=2"),
-                       ("adaptive", "adaptive:c3sl:R=4,min_R=2")):
+    for name, spec in (("static", static_spec), ("adaptive", adaptive_spec)):
         eng = BatchedEngine(params, cfg, num_slots=2, max_len=32,
-                            codec=spec, greedy=True, prefill_mode="decode")
+                            codec=spec, greedy=True)
         if name == "adaptive":
             eng.codec.pin(2)
-        for u in range(3):
-            eng.submit(Request(uid=u, prompt=[1 + u, 2 + u, 3], max_new_tokens=3))
+        for u, (p, mn) in enumerate(reqs):
+            eng.submit(Request(uid=u, prompt=list(p), max_new_tokens=mn))
         outs[name] = {r.uid: r.out for r in eng.run(max_steps=128)}
+        assert len(outs[name]) == len(reqs)
     assert outs["adaptive"] == outs["static"]

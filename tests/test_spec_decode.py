@@ -61,8 +61,7 @@ def _engine(cfg, params, **kw):
     kw.setdefault("max_len", 32)
     kw.setdefault("chunk_size", 8)
     kw.setdefault("sync_every", 4)
-    return BatchedEngine(params, cfg, greedy=True, seed=0,
-                         prefill_mode="chunked", **kw)
+    return BatchedEngine(params, cfg, greedy=True, seed=0, **kw)
 
 
 def _prompt(rng, n, vocab=128):
@@ -451,9 +450,6 @@ def test_engine_spec_validation(setup):
     with pytest.raises(ValueError, match="greedy"):
         BatchedEngine(params, cfg, num_slots=2, max_len=32, greedy=False,
                       spec_decode=SpecConfig())
-    with pytest.raises(ValueError, match="chunked"):
-        BatchedEngine(params, cfg, num_slots=2, max_len=32,
-                      prefill_mode="decode", spec_decode=SpecConfig())
     swa = dataclasses.replace(cfg, sliding_window=4)
     swa_params = lm_lib.init_lm_params(jax.random.PRNGKey(0), swa)
     with pytest.raises(ValueError, match="sliding_window"):
