@@ -15,9 +15,16 @@ Grid: (G/GT, D/T, D/T) with accumulation over the last (j-tile) grid axis.
 Each j-step does R (GT x T) @ (T x T) MXU contractions.  FLOPs match the
 paper's Table 2 accounting (D^2 MACs per bound vector).
 
-VMEM per step (T=128, R=4, GT=8, f32, double-buffered inputs):
-    Z tile 2*8*8*128*4 = 64 KiB (R=4 pads to 8 sublanes), key blocks
-    2*4*128*128*4 = 512 KiB, out 8*128*4 = 4 KiB  -> ~0.6 MiB.
+The row block GT follows from G alone (:func:`_row_block`): the largest
+multiple of 8 dividing G, up to ROW_BLOCK = 256.  Each step fetches R key
+blocks, so GT rows share that fetch: a serving prefill chunk of 256 rows
+per slot group runs a (k, 32, 32) grid at D=4096.  A decode window's
+G = slots/R (4 at 16 slots) keeps GT = G.
+
+VMEM per step (T=128, R=4, GT=256, f32, every block double-buffered):
+    Z (bind in) / Zhat (unbind out) block 2*256*8*128*4 = 2 MiB (R=4 pads
+    to 8 sublanes), key blocks 2*4*128*128*4 = 512 KiB, S block 2*256*128*4
+    = 256 KiB  -> ~2.8 MiB, inside the 16 MiB default scoped VMEM.
 """
 from __future__ import annotations
 
@@ -125,11 +132,29 @@ def execution_mode() -> str:
     return "pallas-interpret" if _interpret() else "pallas-compiled"
 
 
-def _geometry(G: int, D: int, tile: int | None):
+# Most rows a grid step streams through the R key blocks it fetches: the
+# fetch and the step's fixed cost are shared by the rows of the step.
+ROW_BLOCK = 256
+
+
+def _row_block(G: int) -> int:
+    """Rows per grid step: the largest multiple of 8 that divides G and is
+    at most ROW_BLOCK; G itself when G is not a multiple of 8, since the
+    (GT, T) row block needs GT % 8 == 0 or GT == G (TPU tiling)."""
+    if G % 8:
+        return G
+    GT = min(G, ROW_BLOCK) // 8 * 8
+    while G % GT:
+        GT -= 8
+    return GT
+
+
+def _geometry(G: int, D: int, tile: int | None = None):
+    """(T, GT, grid) of a call on G rows of width D: T the D tile, GT the
+    row block, grid (G/GT, D/T, D/T) with the j-tile axis innermost."""
     T = tile or _pick_tile(D)
     _check_tile(D, T)
-    # the (GT, T) output block needs GT % 8 == 0 or GT == G (TPU tiling)
-    GT = 8 if G % 8 == 0 else G
+    GT = _row_block(G)
     return T, GT, (G // GT, D // T, D // T)
 
 

@@ -26,6 +26,11 @@ SHAPES = [
     (3, 5, 96),     # non-power-of-two D, G not multiple of GT tile target
     (16, 16, 128),
     (2, 8, 512),
+    # prefill chunks of 1, 2 and 4 slot groups (256 rows each) at R=4: the
+    # row block grows past 8 rows, up to ROW_BLOCK
+    (256, 4, 512),
+    (512, 4, 512),
+    (1024, 4, 512),
 ]
 
 
@@ -50,6 +55,21 @@ def test_unbind_kernel_matches_ref(G, R, D, dtype):
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), rtol=tol, atol=tol)
     assert got.shape == (G, R, D)
+
+
+@pytest.mark.parametrize("op", ["bind", "unbind"])
+def test_kernels_match_ref_at_model_width(op):
+    """One prefill chunk (256 rows, R=4) at deepseek-7b's D=4096, float32:
+    a grid of 32 x 32 tiles, each key block serving all 256 rows.  (A
+    bfloat16 output accumulates over the 32 j-tiles in bfloat16, which
+    drifts past 5e-2 at this width.)"""
+    Z, K = _data(256, 4, 4096, jnp.float32)
+    S = kref.bind_superpose_ref(Z, K)
+    if op == "bind":
+        got, want = kops.bind_superpose_pallas(Z, K), S
+    else:
+        got, want = kops.unbind_pallas(S, K), kref.unbind_ref(S, K)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("backend", ["fft", "direct"])
@@ -98,6 +118,30 @@ def test_keys_get_no_gradient():
     Z, K = _data(2, 2, 128, jnp.float32)
     g = jax.grad(lambda k: kops.bind_superpose_pallas(Z, k).sum())(K)
     np.testing.assert_array_equal(np.asarray(g), 0.0)
+
+
+@pytest.mark.parametrize("G,GT", [(1, 1), (3, 3), (4, 4), (12, 12),
+                                  (8, 8), (24, 24), (40, 40), (264, 88),
+                                  (256, 256), (512, 256), (1024, 256)])
+def test_row_block_follows_from_G(G, GT):
+    """Decode's G = slots/R = 4 and odd G keep GT = G; a multiple of 8
+    takes the largest multiple of 8 dividing it, up to ROW_BLOCK."""
+    from repro.kernels import circconv
+    D = 4096
+    T, gt, grid = circconv._geometry(G, D)
+    assert (T, gt) == (128, GT)
+    assert grid == (G // GT, D // T, D // T) and G % GT == 0
+
+
+def test_row_block_divides_every_G():
+    from repro.kernels import circconv
+    for G in range(1, 2049):
+        GT = circconv._row_block(G)
+        assert G % GT == 0
+        if G % 8:
+            assert GT == G
+        else:
+            assert GT % 8 == 0 and GT <= circconv.ROW_BLOCK
 
 
 # ---------------------------------------------------------------------------

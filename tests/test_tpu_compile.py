@@ -54,8 +54,13 @@ def one_chip():
 @pytest.fixture
 def compiled_kernels(monkeypatch):
     # the kernels pick interpret mode from the default backend, which is
-    # the CPU here; the described chip takes the compiled path
+    # the CPU here; the described chip takes the compiled path.  Interpret
+    # is read at trace time, so no trace of the other mode may be reused:
+    # another test in this process may have traced the same shapes.
+    jax.clear_caches()
     monkeypatch.setattr(circconv, "_interpret", lambda: False)
+    yield
+    jax.clear_caches()
 
 
 def _spec(sharding, shape, dtype=jnp.float32):
@@ -67,15 +72,27 @@ def _assert_kernel(fn, *args):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("op", ["bind", "unbind"])
-def test_circconv_compiles_for_v5e(one_chip, compiled_kernels, op):
-    G = B // R
-    keys = _spec(one_chip, (R, D))
+def _assert_codec_kernel(sharding, op, G):
+    keys = _spec(sharding, (R, D))
     if op == "bind":
         _assert_kernel(circconv.bind_superpose_kernel,
-                       _spec(one_chip, (G, R, D)), keys)
+                       _spec(sharding, (G, R, D)), keys)
     else:
-        _assert_kernel(circconv.unbind_kernel, _spec(one_chip, (G, D)), keys)
+        _assert_kernel(circconv.unbind_kernel, _spec(sharding, (G, D)), keys)
+
+
+@pytest.mark.parametrize("op", ["bind", "unbind"])
+def test_circconv_compiles_for_v5e(one_chip, compiled_kernels, op):
+    _assert_codec_kernel(one_chip, op, B // R)
+
+
+@pytest.mark.parametrize("op", ["bind", "unbind"])
+@pytest.mark.parametrize("G", [4, 256, 512, 1024])
+def test_circconv_compiles_at_serve_shapes(one_chip, compiled_kernels, op, G):
+    """The serve cell's codec calls: a decode window's 16 slots / R = 4
+    groups, and prefill chunks of 1, 2 and 4 slot groups of 256 rows, whose
+    row blocks of up to ROW_BLOCK rows must fit VMEM."""
+    _assert_codec_kernel(one_chip, op, G)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8],
